@@ -26,12 +26,13 @@ has two implementations:
 Memory spaces (``common.GridPlan``, DESIGN.md §4.7): the ``vmem`` tilings
 keep the whole compacted ``(nblocks, cap)`` plane (gather) / every level's
 block-tile rows (compaction) resident per grid step.  On the ``hbm`` path
-the prefix tables ride as scalar-prefetch operands and the planes stay in
-HBM: compaction becomes a pure HBM→HBM DMA program (level rows → their
-static columns), and the gather DMAs, per output tile, exactly the block
-rows that tile spans — the span bounds ``[lo_t, hi_t)`` are precomputed from
-the prefix table (``ops``) and prefetched, so the dynamic-trip row loop
-costs sum-of-spans ≈ nblocks + ntiles DMAs total.
+the planes stay in HBM: compaction is one HBM→HBM DMA per level (level →
+its static columns), and the gather lays the compacted plane out as
+``(nblocks, rows, 128)`` and, per output tile of ``DEFAULT_SEG_TILE``
+indices, walks the block span ``[lo_t, hi_t)`` precomputed from the prefix
+table: for each block it DMAs the aligned window of the block's row that
+holds the tile's slice and shifts it into place with lane and sublane rolls
+(Mosaic has no 1-D gather) — Σ spans ≈ nblocks + ntiles window DMAs.
 """
 from __future__ import annotations
 
@@ -61,7 +62,7 @@ def _seg_ctr(ctr_ref, t, lo, hi):
     ])
 
 DEFAULT_BLOCK_TILE = 8
-DEFAULT_SEG_TILE = 256
+DEFAULT_SEG_TILE = 4096  # 32 rows of 128 lanes per output tile
 
 
 # --------------------------------------------------------------------------
@@ -76,18 +77,19 @@ def _compact_vmem(*refs, starts):
         out[:, starts[b] : starts[b] + size] = ref[...]
 
 
-def _compact_hbm(*refs, starts, sizes, block_tile):
-    """Pure DMA program: level rows → their static output columns (HBM→HBM)."""
+def _compact_hbm(*refs, starts, sizes):
+    """Pure DMA program: each level → its static output columns (HBM→HBM),
+    all rows at once; every copy is started before any is awaited."""
     *levels, out, sem = refs
-    i = pl.program_id(0)
-    rows = pl.ds(i * block_tile, block_tile)
-    for b, ref in enumerate(levels):
-        cp = pltpu.make_async_copy(
-            ref.at[rows],
-            out.at[rows, pl.ds(starts[b], sizes[b])],
-            sem,
+    copies = [
+        pltpu.make_async_copy(
+            ref, out.at[:, pl.ds(starts[b], sizes[b])], sem.at[b]
         )
+        for b, ref in enumerate(levels)
+    ]
+    for cp in copies:
         cp.start()
+    for cp in copies:
         cp.wait()
 
 
@@ -112,16 +114,14 @@ def compact_blocks_pallas(
         any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
         plan = common.GridPlan(
             memory_space="hbm",
-            grid=(nblocks // block_tile,),
+            grid=(1,),
             num_tables=0,
             table_specs=(),
             in_specs=[any_spec] * nbuckets,
             out_specs=any_spec,
-            scratch_shapes=[pltpu.SemaphoreType.DMA],
+            scratch_shapes=[pltpu.SemaphoreType.DMA((nbuckets,))],
         )
-        kernel = functools.partial(
-            _compact_hbm, starts=starts, sizes=sizes, block_tile=block_tile
-        )
+        kernel = functools.partial(_compact_hbm, starts=starts, sizes=sizes)
         return plan.pallas_call(kernel, out_shape, interpret=interpret)(*buckets)
     plan = common.GridPlan(
         memory_space="vmem",
@@ -176,33 +176,61 @@ def _seg_gather_vmem(
 
 
 def _seg_gather_hbm(
-    starts_ref, ends_ref, lo_ref, hi_ref, compact_ref, *refs,
-    seg_tile, instrument=False,
+    starts_ref, ends_ref, lo_ref, hi_ref, last_ref, compact_ref, *refs,
+    cap, instrument=False,
 ):
-    o_ref, row, sem = refs[0], refs[-2], refs[-1]
-    """One output tile, compact plane in HBM.
+    """One ``(R, 128)`` output tile (``R·128`` consecutive global indices).
 
-    The tile's block span ``[lo_t, hi_t)`` was precomputed from the prefix
-    table; the dynamic-trip loop DMAs one block row at a time and claims the
-    lanes whose global index falls inside that block's ``[start, end)``
-    interval — intervals are disjoint, so each live lane is claimed exactly
-    once and dead lanes keep the zero init.
+    ``compact`` is the row-compacted plane laid out ``(nblocks, rows, 128)``
+    in HBM.  For each block of the tile's precomputed span ``[lo_t, hi_t)``
+    the output is the block's row shifted by ``d = tile_base − start``:
+    ``out[q] = row[q + d]``.  The kernel DMAs the aligned ``2R``-row window
+    of the row that holds ``[d, d + R·128)``, then shifts it on the VPU —
+    a lane roll by ``d mod 128`` and a sublane roll by ``d // 128``, the
+    lanes past the carry taken from the next row — and claims the lanes
+    whose global index falls in the block's ``[start, end)``.  Intervals
+    are disjoint, so each live lane is claimed once; dead lanes stay 0.
+    Positions past ``cap`` (a size that overflowed its capacity) repeat the
+    row's last slot, as the oracle's clamped index does — ``last`` holds
+    those slots' words.
     """
+    o_ref, buf, sem = refs[0], refs[-2], refs[-1]
     t = pl.program_id(0)
-    cap = compact_ref.shape[1]
-    idx = t * seg_tile + jax.lax.broadcasted_iota(jnp.int32, (seg_tile, 1), 0)[:, 0]
+    R = o_ref.shape[0]
+    W = buf.shape[0]  # 2R window rows
+    nrows = compact_ref.shape[1]
+    align = common.tile_rows(compact_ref.dtype)
+    base = t * (R * 128)
+    q = (
+        jax.lax.broadcasted_iota(jnp.int32, (R, 128), 0) * 128
+        + jax.lax.broadcasted_iota(jnp.int32, (R, 128), 1)
+    )
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, 128), 1)
 
     def claim(b, acc):
-        cp = pltpu.make_async_copy(compact_ref.at[pl.ds(b, 1)], row, sem)
+        s, e = starts_ref[b], ends_ref[b]
+        d = base - s
+        r0 = jnp.minimum(
+            (jnp.maximum(d, 0) // (align * 128)) * align, nrows - W
+        )
+        r0 = pl.multiple_of(r0, align)
+        cp = pltpu.make_async_copy(compact_ref.at[b, pl.ds(r0, W)], buf, sem)
         cp.start()
         cp.wait()
-        s, e = starts_ref[b], ends_ref[b]
-        take = (idx >= s) & (idx < e)
-        vals = jnp.take(row[0], jnp.clip(idx - s, 0, cap - 1))
-        return jnp.where(take, vals, acc)
+        dd = d - r0 * 128 + W * 128  # shifted positive: dd ∈ (W·64, 2W·128)
+        k = dd // 128 - W  # sublane shift, may be negative
+        l = dd % 128  # lane shift
+        y = pltpu.roll(common.to_words(buf[...]), (128 - l) % 128, 1)
+        z0 = pltpu.roll(y, (W - k) % W, 0)[:R]  # z0[s] = y[s + k]
+        z1 = pltpu.roll(y, (2 * W - k - 1) % W, 0)[:R]  # z1[s] = y[s + k + 1]
+        vals = jnp.where(lane < 128 - l, z0, z1)
+        g = base + q
+        vals = jnp.where(g - s >= cap, last_ref[b], vals)
+        return jnp.where((g >= s) & (g < e), vals, acc)
 
-    zero = jnp.zeros((seg_tile,), o_ref.dtype)
-    o_ref[0, :] = jax.lax.fori_loop(lo_ref[t], hi_ref[t], claim, zero)
+    zero = jnp.zeros((R, 128), jnp.int32)
+    acc = jax.lax.fori_loop(lo_ref[t], hi_ref[t], claim, zero)
+    o_ref[...] = common.from_words(acc, o_ref.dtype)
     if instrument:
         _seg_ctr(refs[1], t, lo_ref[t], hi_ref[t])
 
@@ -232,34 +260,46 @@ def segmented_gather_pallas(
     ends = ends.reshape(nblocks).astype(jnp.int32)
     out_shape = jax.ShapeDtypeStruct((1, total_pad), compact.dtype)
     if memory_space == "hbm":
+        align = common.tile_rows(compact.dtype)
+        if seg_tile % (128 * align):
+            raise ValueError(f"hbm seg_tile {seg_tile} must hold whole tiles")
+        R = seg_tile // 128
         # per-tile block spans off the prefix table (ops-level jnp, tiny)
         tbase = jnp.arange(ntiles, dtype=jnp.int32) * seg_tile
         lo = jnp.maximum(
             jnp.sum(starts[None, :] <= tbase[:, None], axis=1) - 1, 0
         )
         hi = jnp.sum(starts[None, :] <= (tbase + seg_tile - 1)[:, None], axis=1)
+        # rows of 128 lanes per block, at least one 2R-row window, aligned
+        nrows = max(-(-cap // 128), 2 * R)
+        nrows += (-nrows) % align
+        plane = jnp.pad(compact, ((0, 0), (0, nrows * 128 - cap)))
+        plane = plane.reshape(nblocks, nrows, 128)
         plan = common.GridPlan(
             memory_space="hbm",
             grid=(ntiles,),
-            num_tables=4,
+            num_tables=5,
             table_specs=(),
             in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec((1, seg_tile), lambda t, s, e, lo, hi: (0, t)),
+            out_specs=pl.BlockSpec((R, 128), lambda t, *tables: (t, 0)),
             scratch_shapes=[
-                pltpu.VMEM((1, cap), compact.dtype),
+                pltpu.VMEM((2 * R, 128), compact.dtype),
                 pltpu.SemaphoreType.DMA,
             ],
             instrument=instrument,
         )
         kernel = functools.partial(
-            _seg_gather_hbm, seg_tile=seg_tile, instrument=instrument
+            _seg_gather_hbm, cap=cap, instrument=instrument
         )
-        outs = plan.pallas_call(kernel, out_shape, interpret=interpret)(
-            starts, ends, lo, hi, compact
-        )
+        last = common.to_words(compact[:, cap - 1])
+        outs = plan.pallas_call(
+            kernel,
+            jax.ShapeDtypeStruct((ntiles * R, 128), compact.dtype),
+            interpret=interpret,
+        )(starts, ends, lo, hi, last, plane)
         if instrument:
-            return outs[0][0, :total], outs[1]
-        return outs[0, :total]
+            return outs[0].reshape(-1)[:total], outs[1]
+        return outs.reshape(-1)[:total]
     plan = common.GridPlan(
         memory_space="vmem",
         grid=(ntiles,),

@@ -10,7 +10,8 @@ built on the shared :class:`repro.kernels.common.GridPlan` memory-space layer
     one grid step per row tile against the resident pool.  hbm: grid
     ``(narrays, pages)`` with the page table scalar-prefetched; the pool
     ``index_map`` reads ``pages[n, p]`` so each grid step DMAs exactly the
-    one slab tile it emits.
+    one slab tile it emits.  Scalar-item pools stay 2-D ``(S, T)`` and take
+    :func:`paged_gather_rows`, which DMAs each slab through its HBM row band.
 
 ``paged_attend_pallas``
     Flash-decode attention against paged K/V pools: grid ``(batch, kv_heads,
@@ -21,18 +22,17 @@ built on the shared :class:`repro.kernels.common.GridPlan` memory-space layer
     K/V ``index_map`` DMAs one ``(slab_tokens, head_dim)`` tile per step
     instead of holding the pools resident.
 
-``slab_append_pallas``
-    The push_back prefix-sum machinery (exclusive mask scan + insert
-    permutation, see ``kernels/push_back``) retargeted at the pool: each grid
-    step resolves its slab's wave elements through the slab's *owner* row,
-    and the pool aliases its output so untouched slabs are never copied.
-    hbm: one slab per grid step, with the owner/base/size tables
-    scalar-prefetched — the owner table drives the wave-row ``index_map``, so
-    only the owning array's wave lane block is DMA'd alongside the slab tile.
-    Waves at least ``common.MXU_DISPATCH_WAVE`` lanes wide apply the insert
-    permutation as an MXU dispatch matmul (``kernels/dispatch_mxu``) instead
-    of the exact int32 one-hot reduction — bit-exact for f32-representable
-    payloads (``common.resolve_dispatch``).
+``slab_append_pallas`` / ``slab_append_hbm``
+    The push_back insert (exclusive mask scan + insert permutation, see
+    ``kernels/push_back``) retargeted at the pool; the pool aliases its
+    output so untouched slabs are never copied.  vmem: each grid step
+    resolves its slab tile's wave elements through the slab's *owner* row;
+    waves at least ``common.MXU_DISPATCH_WAVE`` lanes wide apply the insert
+    permutation as an MXU dispatch matmul (``kernels/dispatch_mxu``).  hbm:
+    one grid step per *array*, which reads, fills and writes back only the
+    few slabs its wave lands in (a table built by ``ops`` from the owner and
+    base tables) — O(wave) traffic whatever the pool size — placing the
+    elements with the exact byte-plane matmul ``common.wave_select``.
 """
 from __future__ import annotations
 
@@ -51,9 +51,11 @@ from repro.obs import device
 __all__ = [
     "paged_gather_pallas",
     "paged_gather_pallas_extents",
+    "paged_gather_rows",
     "paged_attend_pallas",
     "paged_attend_pallas_extents",
     "slab_append_pallas",
+    "slab_append_hbm",
     "DEFAULT_ROW_TILE",
 ]
 
@@ -119,6 +121,93 @@ def _gather_hbm(pages_ref, pool_ref, *refs, instrument=False):
             ("paged_gather.tiles", live),
             ("paged_gather.masked_tiles", 1 - live),
         ])
+
+
+def _gather_hbm_rows(pages_ref, pool_ref, out_ref, *refs, n_valid, instrument):
+    """Scalar-item pool ``(S, T)``: one ``(tile_rows, T)`` output tile —
+    page ``p`` of ``tile_rows`` arrays — per grid step.  Each array's slab
+    row is read through its aligned :func:`common.row_window` and placed in
+    its output row; page −1 rows stay zero.  ``pages_ref`` is this tile's
+    rows of the page table, blocked into SMEM (the whole table can outgrow
+    SMEM's 1 MiB, so it is not scalar-prefetched)."""
+    if instrument:
+        ctr_ref, buf, acc, sem = refs
+    else:
+        buf, acc, sem = refs
+    i, p = pl.program_id(0), pl.program_id(1)
+    tr, T = out_ref.shape
+    acc[...] = jnp.zeros(acc.shape, jnp.int32)
+    out_row = jax.lax.broadcasted_iota(jnp.int32, (tr, T), 0)
+    live = jnp.zeros((), jnp.int32)
+    for r in range(tr):
+        slab = pages_ref[r, p]
+
+        @pl.when(slab >= 0)
+        def _(r=r, slab=slab):
+            view, rr = common.row_window(pool_ref, slab, 0, T)
+            cp = pltpu.make_async_copy(view, buf, sem)
+            cp.start()
+            cp.wait()
+            words = common.to_words(buf[...])
+            band = jax.lax.broadcasted_iota(jnp.int32, words.shape, 0)
+            row = jnp.sum(jnp.where(band == rr, words, 0), axis=0, keepdims=True)
+            acc[...] = jnp.where(out_row == r, row, acc[...])
+
+        live = live + jnp.where(slab >= 0, 1, 0)
+    out_ref[...] = common.from_words(acc[...], out_ref.dtype)
+    if instrument:
+        first = (i == 0) & (p == 0)
+        real = jnp.clip(n_valid - i * tr, 0, tr)
+        device.ctr_accum(ctr_ref, first, [
+            ("paged_gather.launches", jnp.where(first, 1, 0)),
+            ("paged_gather.tiles", live),
+            ("paged_gather.masked_tiles", real - live),
+        ])
+
+
+def paged_gather_rows(
+    pool: jax.Array,  # (S, T) scalar items
+    pages: jax.Array,  # (N, P) int32
+    *,
+    instrument: bool = False,
+    interpret: bool = False,
+):
+    """hbm gather of a scalar-item pool → (N, P·T) logical views.
+
+    A 2-D pool keeps its slabs in tiled rows (a unit feature axis would pad
+    every HBM tile 128-fold), so this grids over ``(tile_rows, T)`` output
+    tiles and DMAs each slab through its row band.  Rows are padded with
+    page −1 to the tile.  With ``instrument=True`` → (out, counter block).
+    """
+    N, P = pages.shape
+    S, T = pool.shape
+    tr = common.tile_rows(pool.dtype)
+    pages_p = common.pad_to(pages, tr, axis=0, value=-1)
+    Np = pages_p.shape[0]
+    plan = common.GridPlan(
+        memory_space="hbm",
+        grid=(Np // tr, P),
+        num_tables=0,
+        table_specs=(),
+        in_specs=[
+            pl.BlockSpec((tr, P), lambda i, p: (i, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.ANY),
+        ],
+        out_specs=pl.BlockSpec((tr, T), lambda i, p: (i, p)),
+        scratch_shapes=[
+            pltpu.VMEM((min(S, tr), T), pool.dtype),
+            pltpu.VMEM((tr, T), jnp.int32),
+            pltpu.SemaphoreType.DMA,
+        ],
+        instrument=instrument,
+    )
+    kernel = functools.partial(_gather_hbm_rows, n_valid=N, instrument=instrument)
+    outs = plan.pallas_call(
+        kernel, jax.ShapeDtypeStruct((Np, P * T), pool.dtype), interpret=interpret
+    )(pages_p, pool)
+    if instrument:
+        return outs[0][:N], outs[1]
+    return outs[:N]
 
 
 def paged_gather_pallas(
@@ -660,27 +749,74 @@ def _slab_append_vmem(
 
 
 def _slab_append_hbm(
-    owners_ref, bases_ref, sizes_ref, mask_ref, elems_ref, pool_in_ref,
-    pool_out_ref, *, narrays, dispatch,
+    tbl_ref, first_ref, counts_ref, off_ref, planes_ref, pool_in_ref,
+    pool_ref, buf, sem, *, spans,
 ):
-    s = pl.program_id(0)
-    owner = owners_ref[s]
-    own = jnp.clip(owner, 0, narrays - 1)
-    mask = mask_ref[...]  # (1, m) — the owner's wave row (this step's DMA)
-    elems = elems_ref[...]  # (1, m, D)
-    _, m = mask.shape
-    inc = jnp.cumsum(mask, axis=1)
-    off = inc - mask
-    count = inc[:, -1:]  # (1, 1)
-    gathered = apply_insert_permutation(off, mask, elems, dispatch)  # (1, m, D)
-    pool_out_ref[...] = _slab_scatter(
-        gathered,
-        owner.reshape(1),
-        bases_ref[s].reshape(1, 1),
-        sizes_ref[own].reshape(1, 1),
-        count,
-        pool_in_ref[...],
-        m,
+    """One array per grid step: read-modify-write the slabs its wave writes.
+
+    ``tbl[a·spans + j]`` is the ``j``-th slab array ``a``'s wave touches (−1
+    when none) and ``first`` the wave offset landing on its slot 0; the
+    slab is read, filled and written back in place through its aligned
+    :func:`common.row_window` by :func:`common.fill_window`.
+    """
+    a = pl.program_id(0)
+    T = buf.shape[1]
+    count = counts_ref[a]
+    for j in range(spans):
+        slab = tbl_ref[a * spans + j]
+
+        @pl.when(slab >= 0)
+        def _(j=j, slab=slab):
+            view, rr = common.row_window(pool_ref, slab, 0, T)
+            common.fill_window(
+                view, rr, buf, sem, planes_ref[0], off_ref[0],
+                first_ref[a * spans + j], count,
+            )
+
+
+def slab_append_hbm(
+    pool: jax.Array,  # (S, T) scalar items or (S, T, D)
+    tbl: jax.Array,  # (N, spans) int32 — touched slab ids, −1 none
+    first: jax.Array,  # (N, spans) int32 — wave offset at each slab's slot 0
+    counts: jax.Array,  # (N,) int32 — masked lanes per array
+    off: jax.Array,  # (N, m) int32 — exclusive prefix sums, −1 masked
+    planes: jax.Array,  # (N, 4, m[, D]) bf16 byte planes of the wave
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """The hbm tiling of slab-append → new pool, aliased in place.
+
+    Grids over arrays, not slabs: only the ``spans`` slabs each wave
+    touches move, so an append costs O(wave), whatever the pool size.
+    """
+    N, spans = tbl.shape
+    m = off.shape[1]
+    if pool.ndim == 3:
+        buf = pltpu.VMEM((1, *pool.shape[1:]), pool.dtype)
+    else:
+        rows = min(pool.shape[0], common.tile_rows(pool.dtype))
+        buf = pltpu.VMEM((rows, pool.shape[1]), pool.dtype)
+    lead = lambda k: (lambda a, *_: (a,) + (0,) * (k - 1))
+    plan = common.GridPlan(
+        memory_space="hbm",
+        grid=(N,),
+        num_tables=3,
+        table_specs=(),
+        in_specs=[
+            pl.BlockSpec((1, 1, m), lead(3)),
+            pl.BlockSpec((1, *planes.shape[1:]), lead(planes.ndim)),
+            pl.BlockSpec(memory_space=pltpu.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        scratch_shapes=[buf, pltpu.SemaphoreType.DMA],
+        aliases={2: 0},  # pool in-place: O(wave) writes
+    )
+    kernel = functools.partial(_slab_append_hbm, spans=spans)
+    return plan.pallas_call(
+        kernel, jax.ShapeDtypeStruct(pool.shape, pool.dtype), interpret=interpret
+    )(
+        tbl.reshape(-1), first.reshape(-1), counts,
+        off.reshape(N, 1, m), planes, pool,
     )
 
 
@@ -693,42 +829,17 @@ def slab_append_pallas(
     mask: jax.Array,  # (N, m) int32 0/1
     *,
     slab_tile: int = DEFAULT_ROW_TILE,
-    memory_space: str = "vmem",
     dispatch: str = "onehot",
     interpret: bool = False,
 ) -> jax.Array:
-    """→ new pool (S, T, D); untouched slabs alias through unscathed."""
+    """The vmem tiling → new pool (S, T, D); untouched slabs alias through
+    unscathed."""
     S, T, D = pool.shape
     N, m = mask.shape
     owners = owners.reshape(S).astype(jnp.int32)
     bases = bases.reshape(S).astype(jnp.int32)
     sizes = sizes.reshape(N).astype(jnp.int32)
     out_shape = jax.ShapeDtypeStruct((S, T, D), pool.dtype)
-    if memory_space == "hbm":
-        # one slab per grid step; the scalar-prefetched owner table selects
-        # which array's wave lane block rides along in the DMA.
-        row_of = lambda s, owners, bases, sizes: jnp.clip(owners[s], 0, N - 1)
-        plan = common.GridPlan(
-            memory_space="hbm",
-            grid=(S,),
-            num_tables=3,
-            table_specs=(),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, m), lambda s, ow, ba, si: (row_of(s, ow, ba, si), 0)
-                ),
-                pl.BlockSpec(
-                    (1, m, D), lambda s, ow, ba, si: (row_of(s, ow, ba, si), 0, 0)
-                ),
-                pl.BlockSpec((1, T, D), lambda s, ow, ba, si: (s, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, T, D), lambda s, ow, ba, si: (s, 0, 0)),
-            aliases={2: 0},  # pool in-place: O(wave) writes
-        )
-        kernel = functools.partial(_slab_append_hbm, narrays=N, dispatch=dispatch)
-        return plan.pallas_call(kernel, out_shape, interpret=interpret)(
-            owners, bases, sizes, mask, elems, pool
-        )
     if S % slab_tile:
         raise ValueError(f"n_slabs {S} must divide by tile {slab_tile}")
     row = lambda width: pl.BlockSpec((slab_tile, width), lambda i: (i, 0))

@@ -109,6 +109,13 @@ def paged_gather(
             return out, _gather_ctr(pages, space, _kernel.DEFAULT_ROW_TILE)
         return out
     run = common.should_interpret(interpret)
+    if len(exts) == 1 and exts[0].ndim == 2 and space == "hbm":
+        outs = _kernel.paged_gather_rows(
+            exts[0], pages, instrument=instrument, interpret=run
+        )
+        if instrument:
+            return outs[0], device.from_block(outs[1])
+        return outs
     if len(exts) == 1:
         pool3, item = _flat_item(exts[0], 2)
         outs = _kernel.paged_gather_pallas(
@@ -195,6 +202,34 @@ def paged_attend(
     return out
 
 
+def _touched_slabs(owners, bases, sizes, counts, T: int, m: int):
+    """→ (tbl, first), each (N, spans): the slabs a wave writes, per array.
+
+    Array ``a`` writes positions ``[size, size+count)``: its logical pages
+    ``size // T + j`` for ``j < spans``.  ``tbl`` holds each page's global
+    slab id (−1 when the page is not written or not claimed — the write
+    drops, as in the oracle) and ``first`` the wave offset landing on the
+    page's slot 0.  Built from the per-slab owner/base tables by one
+    scatter.
+    """
+    N = sizes.shape[0]
+    spans = (max(m, 1) - 1) // T + 2
+    page0 = sizes // T
+    q = page0[:, None] + jnp.arange(spans, dtype=jnp.int32)[None, :]
+    first = q * T - sizes[:, None]
+    owners = owners.reshape(-1).astype(jnp.int32)
+    rel = bases.reshape(-1).astype(jnp.int32) // T - page0[
+        jnp.clip(owners, 0, N - 1)
+    ]
+    ok = (owners >= 0) & (rel >= 0) & (rel < spans)
+    key = jnp.where(ok, owners * spans + rel, N * spans)
+    lut = jnp.full((N * spans + 1,), -1, jnp.int32)
+    lut = lut.at[key].set(jnp.arange(owners.shape[0], dtype=jnp.int32))
+    lut = lut[: N * spans].reshape(N, spans)
+    live = (q * T < (sizes + counts)[:, None]) & (counts > 0)[:, None]
+    return jnp.where(live, lut, -1), first
+
+
 def _slab_append(
     pool,  # (S, T, *item) or tuple of extents (S_e, T, *item)
     owners: jax.Array,  # (S,) int32 — owning array per slab, −1 free
@@ -259,53 +294,66 @@ def _slab_append(
         if instrument:
             return new_pool, new_sizes, pos, ctr()
         return new_pool, new_sizes, pos
-    # positions/counts are pure mask arithmetic — recomputed in-kernel for
-    # the scatter, emitted here for the caller (same exclusive scan)
+    # positions/counts are pure mask arithmetic — the hbm kernel takes them
+    # precomputed, the vmem kernel recomputes them in-kernel for the scatter
     mask_i = mask.astype(jnp.int32)
     inc = jnp.cumsum(mask_i, axis=1)
     counts = inc[:, -1]
-    pos = sizes[:, None].astype(jnp.int32) + inc - mask_i
+    sizes32 = sizes.astype(jnp.int32)
+    pos = sizes32[:, None] + inc - mask_i
     space = common.resolve_memory_space(memory_space, interpret)
-    disp = common.resolve_dispatch(dispatch, m, elems.dtype)
     run = common.should_interpret(interpret)
     tile = _kernel.DEFAULT_ROW_TILE
-    elems_p = common.pad_to(elems3, common.MXU_LANE, axis=1)
-    mask_p = common.pad_to(mask_i, common.MXU_LANE, axis=1)
-    sizes32 = sizes.astype(jnp.int32)
+    if space == "hbm":
+        tbl, first = _touched_slabs(owners, bases, sizes32, counts, T, m)
+        planes = common.pad_to(
+            common.byte_planes(elems if not item else elems3, 1),
+            common.MXU_LANE, axis=2,
+        )
+        off = common.pad_to(
+            jnp.where(mask, inc - mask_i, -1), common.MXU_LANE, axis=1, value=-1
+        )
 
-    def one_extent(ext3: jax.Array, lo: int) -> jax.Array:
-        S_e = ext3.shape[0]
-        own_e = jax.lax.dynamic_slice_in_dim(owners.reshape(-1), lo, S_e)
-        base_e = jax.lax.dynamic_slice_in_dim(bases.reshape(-1), lo, S_e)
-        if space == "hbm":
-            pool_p, owners_p, bases_p = ext3, own_e, base_e
-        else:  # padded slabs: owner −1 — provably inert
-            pool_p = common.pad_to(ext3, tile, axis=0)
-            owners_p = common.pad_to(own_e, tile, axis=0, value=-1)
-            bases_p = common.pad_to(base_e, tile, axis=0)
-        return _kernel.slab_append_pallas(
-            pool_p,
-            owners_p,
-            bases_p,
-            sizes32,
-            elems_p,
-            mask_p,
-            memory_space=space,
-            dispatch=disp,
-            interpret=run,
-        )[:S_e]
+        def one_extent(e, lo: int) -> jax.Array:
+            S_e = e.shape[0]
+            view = e if not item else _flat_item(e, 2)[0]
+            mine = (tbl >= lo) & (tbl < lo + S_e)
+            new = _kernel.slab_append_hbm(
+                view, jnp.where(mine, tbl - lo, -1), first, counts, off,
+                planes, interpret=run,
+            )
+            return new.reshape(e.shape)
+
+    else:
+        disp = common.resolve_dispatch(dispatch, m, elems.dtype)
+        elems_p = common.pad_to(elems3, common.MXU_LANE, axis=1)
+        mask_p = common.pad_to(mask_i, common.MXU_LANE, axis=1)
+
+        def one_extent(e, lo: int) -> jax.Array:
+            ext3 = _flat_item(e, 2)[0]
+            S_e = ext3.shape[0]
+            own_e = jax.lax.dynamic_slice_in_dim(owners.reshape(-1), lo, S_e)
+            base_e = jax.lax.dynamic_slice_in_dim(bases.reshape(-1), lo, S_e)
+            # padded slabs: owner −1 — provably inert
+            new = _kernel.slab_append_pallas(
+                common.pad_to(ext3, tile, axis=0),
+                common.pad_to(own_e, tile, axis=0, value=-1),
+                common.pad_to(base_e, tile, axis=0),
+                sizes32,
+                elems_p,
+                mask_p,
+                dispatch=disp,
+                interpret=run,
+            )
+            return new[:S_e].reshape(e.shape)
 
     new_exts, lo = [], 0
-    for e3, _ in ext_item:
-        S_e = e3.shape[0]
-        new_exts.append(e3 if S_e == 0 else one_extent(e3, lo))
-        lo += S_e
+    for e in exts:
+        new_exts.append(e if e.shape[0] == 0 else one_extent(e, lo))
+        lo += e.shape[0]
     new_sizes = sizes + counts
     pos = jnp.where(mask, pos, -1)
-    if not is_multi:
-        new_pool = new_exts[0].reshape(pool.shape)
-    else:
-        new_pool = tuple(ne.reshape(e.shape) for ne, e in zip(new_exts, exts))
+    new_pool = tuple(new_exts) if is_multi else new_exts[0]
     if instrument:
         return new_pool, new_sizes, pos, ctr()
     return new_pool, new_sizes, pos

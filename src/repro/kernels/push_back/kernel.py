@@ -30,16 +30,18 @@ This is what lets the quantized KV-cache decode write k/v/ks/vs in a single
 launch instead of four.
 
 Memory spaces (``common.GridPlan``, DESIGN.md §4.7): the ``vmem`` tiling
-keeps every level's block-tile rows resident per grid step (total =
-per-block capacity · tile rows).  The ``hbm`` tiling leaves the levels in
-HBM (``pltpu.ANY``, aliased in place): a scalar-prefetched *touch table* —
-level ``b`` is touched by a tile iff some row's write interval
-``[size, size+count)`` meets ``[start_b, start_b+width_b)`` — gates explicit
-DMAs that stream exactly the touched level tiles through **two**
-largest-level-sized scratch slots, double-buffered: level ``b+1``'s inbound
-copy is started before level ``b``'s is awaited, so the next level's DMA
-overlaps the current level's scatter + write-back.  Per-step VMEM is two
-level tiles plus the wave, never the whole chain.
+(:func:`push_back_pallas`, interpret-mode only) keeps every level's
+block-tile rows resident per grid step.  The ``hbm`` tiling
+(:func:`push_back_hbm`, the TPU path) leaves the levels in HBM
+(``pltpu.ANY``, aliased in place) and grids over block rows: a row's wave
+lands in the contiguous slots ``[size, size+count)``, so each level it meets
+is touched in at most a few ``HBM_CHUNK``-slot column windows, and only
+those are read, filled and written back.  The offsets come precomputed
+(mask arithmetic, outside the kernel) and the wave is placed by
+``common.wave_select`` — a one-hot bf16 matmul over byte planes, exact for
+every payload bit pattern — because Mosaic lowers neither ``cumsum`` nor a
+3-D gather.  Scalar payloads keep their levels 2-D: a trailing unit feature
+axis would pad every HBM tile 128-fold.
 """
 from __future__ import annotations
 
@@ -55,9 +57,10 @@ from repro.kernels import common
 from repro.kernels.dispatch_mxu import kernel as dispatch_kernel
 from repro.obs import device
 
-__all__ = ["push_back_pallas", "apply_insert_permutation"]
+__all__ = ["push_back_pallas", "push_back_hbm", "apply_insert_permutation"]
 
 DEFAULT_BLOCK_TILE = 8
+HBM_CHUNK = 512  # column window of the hbm tiling (slots, 128-lane aligned)
 
 
 def _ctr_pairs(mask, sizes, count, starts, bsizes):
@@ -160,96 +163,139 @@ def _push_back_vmem(
         device.ctr_accum(refs[nout + 2], first, pairs)
 
 
+def _chunk(width: int) -> int:
+    """Column window of one level for the hbm tiling: the whole level row,
+    or ``HBM_CHUNK`` slots when that tiles it (128-lane aligned)."""
+    return HBM_CHUNK if width > HBM_CHUNK and width % HBM_CHUNK == 0 else width
+
+
 def _push_back_hbm(
-    touch_ref, mask_ref, sizes_ref, *refs, starts, bsizes, ngroups, dispatches,
+    sizes_ref, counts_ref, off_ref, *refs, starts, bsizes, ngroups, spans,
     instrument=False,
 ):
+    """One block row per grid step: read-modify-write its write windows.
+
+    The row's wave lands in ``[size, size+count)``; in level ``b`` that is
+    at most ``spans[b]`` column chunks, each read, filled and written back
+    through its aligned :func:`common.row_window` by
+    :func:`common.fill_window`.
+    """
     nlev = len(bsizes)
-    elems_refs = refs[:ngroups]
-    # level inputs are aliased to the outputs — one HBM buffer; use the outs
-    level_out = refs[ngroups + ngroups * nlev : ngroups + 2 * ngroups * nlev]
-    nout = ngroups + 2 * ngroups * nlev
-    pos_ref = refs[nout]
-    nsz_ref = refs[nout + 1]
-    scratch = refs[-ngroups - 2 : -2]  # per group: (2, rows, max_width, d)
-    sem_in, sem_out = refs[-2], refs[-1]  # (ngroups, 2) DMA semaphores
+    planes = refs[:ngroups]
+    levels = refs[ngroups + ngroups * nlev : ngroups + 2 * ngroups * nlev]
+    bufs = refs[-ngroups * nlev - 1 : -1]
+    sem = refs[-1]
+    n = pl.program_id(0)
+    size, count = sizes_ref[n], counts_ref[n]
+    off = off_ref[0]  # (1, m) — exclusive prefix sums, −1 on masked lanes
+    for g in range(ngroups):
+        for b in range(nlev):
+            width = _chunk(bsizes[b])
+            lo = jnp.maximum(size - starts[b], 0)
+            hi = jnp.minimum(size + count - starts[b], bsizes[b])
+            for j in range(spans[b]):
+                c = lo // width + j
 
-    i = pl.program_id(0)
-    mask = mask_ref[...]
-    sizes = sizes_ref[...]
-    rows, m = mask.shape
+                @pl.when((lo < hi) & (c * width < hi))
+                def _(g=g, b=b, c=c, width=width):
+                    view, rr = common.row_window(
+                        levels[g * nlev + b], n, c * width, width
+                    )
+                    common.fill_window(
+                        view, rr, bufs[g * nlev + b], sem, planes[g][0], off,
+                        starts[b] + c * width - size, count,
+                    )
 
-    inc = jnp.cumsum(mask, axis=1)
-    off = inc - mask
-    count = inc[:, -1:]
-    pos = sizes + off
-
-    gathered = [
-        apply_insert_permutation(off, mask, elems_refs[g][...], dispatches[g])
-        for g in range(ngroups)
-    ]
-
-    # Levels are double-buffered through two scratch slots (slot = b % 2):
-    # level b+1's DMA-in is started *before* waiting on level b's, so the
-    # inbound stream of the next touched level overlaps the current level's
-    # scatter + write-back.  Semaphores are per (group, slot) so in-flight
-    # copies of adjacent levels never alias a wait.
-    def _copies(b, inbound):
-        width = bsizes[b]
-        slot = b % 2
-        out = []
-        for g in range(ngroups):
-            rows_hbm = level_out[g * nlev + b].at[pl.ds(i * rows, rows)]
-            tile = scratch[g].at[slot, :, pl.ds(0, width)]
-            sem = (sem_in if inbound else sem_out).at[g, slot]
-            src, dst = (rows_hbm, tile) if inbound else (tile, rows_hbm)
-            out.append(pltpu.make_async_copy(src, dst, sem))
-        return out
-
-    def start_in(b):
-        @pl.when(touch_ref[i, b] > 0)
-        def _(b=b):
-            for cp in _copies(b, inbound=True):
-                cp.start()
-
-    def finish_level(b):
-        """Wait level ``b``'s tiles in, scatter, start the write-back."""
-
-        @pl.when(touch_ref[i, b] > 0)
-        def _(b=b):
-            slot, width = b % 2, bsizes[b]
-            for cp in _copies(b, inbound=True):
-                cp.wait()
-            for g in range(ngroups):
-                scratch[g][slot, :, :width] = _level_window(
-                    gathered[g], sizes, count, scratch[g][slot, :, :width],
-                    starts[b], width, m,
-                )
-            for cp in _copies(b, inbound=False):
-                cp.start()
-
-    def drain_out(b):
-        @pl.when(touch_ref[i, b] > 0)
-        def _(b=b):
-            for cp in _copies(b, inbound=False):
-                cp.wait()
-
-    for b in range(nlev):
-        if b >= 2:
-            drain_out(b - 2)  # slot b%2 must be clear before reuse
-        start_in(b)
-        if b >= 1:
-            finish_level(b - 1)
-    finish_level(nlev - 1)
-    if nlev >= 2:
-        drain_out(nlev - 2)
-    drain_out(nlev - 1)
-
-    pos_ref[...] = jnp.where(mask > 0, pos, -1)
-    nsz_ref[...] = sizes + count
     if instrument:
-        first, pairs = _ctr_pairs(mask, sizes, count, starts, bsizes)
-        device.ctr_accum(refs[nout + 2], first, pairs)
+        m = off.shape[-1]
+        writes = jnp.zeros((), jnp.int32)
+        for b in range(nlev):
+            lo = jnp.maximum(size, starts[b])
+            hi = jnp.minimum(size + count, starts[b] + bsizes[b])
+            writes = writes + jnp.maximum(hi - lo, 0)
+        first = n == 0
+        device.ctr_accum(refs[ngroups + 2 * ngroups * nlev], first, [
+            ("push_back.waves", jnp.where(first, 1, 0)),
+            ("push_back.lanes", m),
+            ("push_back.active_lanes", count),
+            ("push_back.level_writes", writes),
+        ])
+
+
+def push_back_hbm(
+    bucket_groups: tuple[tuple[jax.Array, ...], ...],  # level b: (rows, B0·2^b[, D_g])
+    sizes: jax.Array,  # (rows,) int32
+    counts: jax.Array,  # (rows,) int32 — masked lanes per row
+    off: jax.Array,  # (rows, m) int32 — exclusive prefix sums, −1 masked
+    plane_groups: tuple[jax.Array, ...],  # byte planes: (rows, 4, m[, D_g])
+    b0: int,
+    *,
+    instrument: bool = False,
+    interpret: bool = False,
+) -> tuple:
+    """→ new level groups (aliased in place); + counter block if instrumented.
+
+    The hbm tiling of the fused push-back: levels stay in HBM and only the
+    column windows a row's wave writes are moved — O(wave) traffic per
+    append, whatever the capacity.  ``counts`` and ``off`` are mask
+    arithmetic computed by the caller, so the kernel runs no prefix scan.
+    Scalar-item levels are 2-D and their rows must be padded to
+    :func:`common.tile_rows`.
+    """
+    ngroups = len(plane_groups)
+    rows, m = off.shape
+    nlev = len(bucket_groups[0])
+    starts = indexing.bucket_starts(b0, nlev)
+    bsizes = indexing.bucket_sizes(b0, nlev)
+    max_count = m if m else 1
+    spans = tuple(
+        min(w // _chunk(w), (max_count - 1) // _chunk(w) + 2) for w in bsizes
+    )
+    levels = [lvl for grp in bucket_groups for lvl in grp]
+    nl = len(levels)
+    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+
+    def buf(lvl):
+        width = _chunk(lvl.shape[1])
+        if lvl.ndim == 3:
+            return pltpu.VMEM((1, width, lvl.shape[2]), lvl.dtype)
+        return pltpu.VMEM(
+            (min(lvl.shape[0], common.tile_rows(lvl.dtype)), width), lvl.dtype
+        )
+
+    plan = common.GridPlan(
+        memory_space="hbm",
+        grid=(rows,),
+        num_tables=2,
+        table_specs=(),
+        in_specs=[pl.BlockSpec((1, 1, m), lambda n, s, c: (n, 0, 0))]
+        + [
+            pl.BlockSpec(
+                (1, *p.shape[1:]), lambda n, s, c, k=p.ndim: (n,) + (0,) * (k - 1)
+            )
+            for p in plane_groups
+        ]
+        + [any_spec] * nl,
+        out_specs=[any_spec] * nl,
+        scratch_shapes=[buf(lvl) for lvl in levels] + [pltpu.SemaphoreType.DMA],
+        aliases={1 + ngroups + i: i for i in range(nl)},
+        instrument=instrument,
+    )
+    kernel = functools.partial(
+        _push_back_hbm, starts=starts, bsizes=bsizes, ngroups=ngroups,
+        spans=spans, instrument=instrument,
+    )
+    outs = plan.pallas_call(
+        kernel,
+        [jax.ShapeDtypeStruct(lvl.shape, lvl.dtype) for lvl in levels],
+        interpret=interpret,
+    )(sizes, counts, off.reshape(rows, 1, m), *plane_groups, *levels)
+    groups = tuple(
+        tuple(outs[g * nlev : (g + 1) * nlev]) for g in range(ngroups)
+    )
+    if instrument:
+        return groups, outs[nl]
+    return groups
 
 
 def push_back_pallas(
@@ -260,13 +306,12 @@ def push_back_pallas(
     mask: jax.Array,  # (nblocks, m) int32 0/1
     *,
     block_tile: int = DEFAULT_BLOCK_TILE,
-    memory_space: str = "vmem",
     dispatches: tuple[str, ...] | None = None,
-    touch: jax.Array | None = None,  # (ntiles, nlev) int32 — hbm level gating
     instrument: bool = False,
     interpret: bool = False,
 ) -> tuple:
-    """→ (new level groups, positions (−1 where masked), new sizes (nblocks, 1)).
+    """The vmem tiling → (new level groups, positions (−1 where masked),
+    new sizes (nblocks, 1)).
 
     With ``instrument=True`` the tuple gains a trailing (8, 128) int32
     counter block (``obs/device`` layout) accumulated in-kernel.
@@ -297,74 +342,28 @@ def push_back_pallas(
     nl = ngroups * nlev
     # level inputs alias their outputs: untouched slots are never copied.
     aliases = {2 + ngroups + i: i for i in range(nl)}
-    if memory_space == "hbm":
-        if touch is None:
-            raise ValueError("hbm push_back needs the level-touch table")
-        any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        plan = common.GridPlan(
-            memory_space="hbm",
-            grid=(nblocks // block_tile,),
-            num_tables=1,
-            table_specs=(),
-            in_specs=[
-                pl.BlockSpec((block_tile, m), lambda i, touch: (i, 0)),
-                pl.BlockSpec((block_tile, 1), lambda i, touch: (i, 0)),
-            ]
-            + [
-                pl.BlockSpec((block_tile, m, d), lambda i, touch: (i, 0, 0))
-                for d in dims
-            ]
-            + [any_spec] * nl,
-            out_specs=[any_spec] * nl
-            + [
-                pl.BlockSpec((block_tile, m), lambda i, touch: (i, 0)),
-                pl.BlockSpec((block_tile, 1), lambda i, touch: (i, 0)),
-            ],
-            scratch_shapes=[
-                # two slots per group — level b+1 streams into slot (b+1)%2
-                # while level b is scattered/written back from slot b%2
-                pltpu.VMEM((2, block_tile, bsizes[-1], d), grp[0].dtype)
-                for grp, d in zip(bucket_groups, dims)
-            ]
-            + [
-                pltpu.SemaphoreType.DMA((ngroups, 2)),
-                pltpu.SemaphoreType.DMA((ngroups, 2)),
-            ],
-            aliases=aliases,
-            instrument=instrument,
-        )
-        kernel = functools.partial(
-            _push_back_hbm,
-            starts=starts, bsizes=bsizes, ngroups=ngroups, dispatches=dispatches,
-            instrument=instrument,
-        )
-        outs = plan.pallas_call(kernel, out_shape, interpret=interpret)(
-            touch, mask, sizes, *elem_groups,
-            *(lvl for grp in bucket_groups for lvl in grp),
-        )
-    else:
-        level_specs = [item_spec(sz, d) for d in dims for sz in bsizes]
-        plan = common.GridPlan(
-            memory_space="vmem",
-            grid=(nblocks // block_tile,),
-            num_tables=0,
-            table_specs=(),
-            in_specs=[row_spec(m), row_spec(1)]
-            + [item_spec(m, d) for d in dims]
-            + level_specs,
-            out_specs=level_specs + [row_spec(m), row_spec(1)],
-            aliases=aliases,
-            instrument=instrument,
-        )
-        kernel = functools.partial(
-            _push_back_vmem,
-            starts=starts, bsizes=bsizes, ngroups=ngroups, dispatches=dispatches,
-            instrument=instrument,
-        )
-        outs = plan.pallas_call(kernel, out_shape, interpret=interpret)(
-            mask, sizes, *elem_groups,
-            *(lvl for grp in bucket_groups for lvl in grp),
-        )
+    level_specs = [item_spec(sz, d) for d in dims for sz in bsizes]
+    plan = common.GridPlan(
+        memory_space="vmem",
+        grid=(nblocks // block_tile,),
+        num_tables=0,
+        table_specs=(),
+        in_specs=[row_spec(m), row_spec(1)]
+        + [item_spec(m, d) for d in dims]
+        + level_specs,
+        out_specs=level_specs + [row_spec(m), row_spec(1)],
+        aliases=aliases,
+        instrument=instrument,
+    )
+    kernel = functools.partial(
+        _push_back_vmem,
+        starts=starts, bsizes=bsizes, ngroups=ngroups, dispatches=dispatches,
+        instrument=instrument,
+    )
+    outs = plan.pallas_call(kernel, out_shape, interpret=interpret)(
+        mask, sizes, *elem_groups,
+        *(lvl for grp in bucket_groups for lvl in grp),
+    )
     groups = tuple(
         tuple(outs[g * nlev : (g + 1) * nlev]) for g in range(ngroups)
     )

@@ -16,11 +16,11 @@ int8 quant scales) this way (``serving/kvcache.py::append``).
 
 ``memory_space`` selects the kernel tiling (``common.resolve_memory_space``:
 explicit > ``REPRO_MEMORY_SPACE`` > hbm on TPU / vmem in interpret mode).
-The hbm tiling additionally takes a *level-touch table* computed here — per
-block tile and level, whether any row's write interval ``[size, size+count)``
-meets the level — which is what lets the kernel DMA only the touched level
-tiles out of HBM.  ``dispatch`` selects the insert-permutation backend per
-payload group (``common.resolve_dispatch``: ``"auto"`` routes waves at least
+The hbm tiling takes the mask's exclusive prefix sums and lane counts and
+the payload's byte planes, all computed here once per wave, and keeps
+scalar-item levels 2-D.  ``dispatch`` applies to the vmem tiling, whose
+insert-permutation backend it selects per payload group
+(``common.resolve_dispatch``: ``"auto"`` routes waves at least
 ``MXU_DISPATCH_WAVE`` lanes wide through the MXU dispatch matmul).
 """
 from __future__ import annotations
@@ -39,11 +39,20 @@ from repro.obs import device
 __all__ = ["push_back_fused", "push_back_fused_multi"]
 
 
-def _oracle_counters(mask, sizes, b0, nlev, nblocks, m):
+def _row_tile(bucket_groups, item_shapes) -> int:
+    """Row padding of a wave: the kernel tile, raised to the HBM row tile of
+    any 2-D (scalar-item) level so its row windows stay aligned."""
+    tile = _kernel.DEFAULT_BLOCK_TILE
+    for grp, item in zip(bucket_groups, item_shapes):
+        if not item:
+            tile = max(tile, common.tile_rows(grp[0].dtype))
+    return tile
+
+
+def _oracle_counters(mask, sizes, b0, nlev, nblocks, m, tile):
     """jnp device counters matching the in-kernel block's accounting: the
     same padded-wave geometry the fused kernel runs, so the use_ref path
     reports identical numbers (cross-checked in tests)."""
-    tile = _kernel.DEFAULT_BLOCK_TILE
     rows_pad = nblocks + (-nblocks) % tile
     m_pad = m + (-m) % common.MXU_LANE
     starts = jnp.asarray(indexing.bucket_starts(b0, nlev), jnp.int32)
@@ -62,20 +71,6 @@ def _oracle_counters(mask, sizes, b0, nlev, nblocks, m):
         "push_back.padded_lanes": rows_pad * m_pad - nblocks * m,
         "push_back.level_writes": writes,
     })
-
-
-def _level_touch(
-    sizes: jax.Array, mask_i: jax.Array, b0: int, nlev: int, block_tile: int
-) -> jax.Array:
-    """→ (ntiles, nlev) int32: does any row in the tile write into level b?"""
-    starts = jnp.asarray(indexing.bucket_starts(b0, nlev), jnp.int32)
-    ends = starts + jnp.asarray(indexing.bucket_sizes(b0, nlev), jnp.int32)
-    lo = sizes.astype(jnp.int32)  # (nblocks,)
-    hi = lo + jnp.sum(mask_i, axis=1, dtype=jnp.int32)
-    row = (hi[:, None] > starts[None, :]) & (lo[:, None] < ends[None, :])
-    return (
-        row.reshape(-1, block_tile, nlev).any(axis=1).astype(jnp.int32)
-    )
 
 
 @partial(
@@ -118,15 +113,16 @@ def push_back_fused_multi(
             levels, new_sizes, pos = _ref.push_back(buckets, sizes, b0, elems, mask)
             groups.append(levels)
         if instrument:
-            vec = _oracle_counters(mask, sizes, b0, nlev, nblocks, m)
+            tile = _row_tile(bucket_groups, [e.shape[2:] for e in elem_groups])
+            vec = _oracle_counters(mask, sizes, b0, nlev, nblocks, m, tile)
             return tuple(groups), new_sizes, pos, vec
         return tuple(groups), new_sizes, pos
 
     space = common.resolve_memory_space(memory_space, interpret)
+    run = common.should_interpret(interpret)
     item_shapes = [e.shape[2:] for e in elem_groups]
-    dispatches = tuple(
-        common.resolve_dispatch(dispatch, m, e.dtype) for e in elem_groups
-    )
+    tile = _row_tile(bucket_groups, item_shapes)
+    row_pad = (-nblocks) % tile
 
     def flat(x, item):
         d = 1
@@ -134,39 +130,73 @@ def push_back_fused_multi(
             d *= dim
         return x.reshape(*x.shape[: x.ndim - len(item)], d)
 
-    tile = _kernel.DEFAULT_BLOCK_TILE
-    row_pad = (-nblocks) % tile
+    def rows(x, value=0):  # padded rows: mask all-False, sizes 0 — inert
+        return common.pad_to(x, tile, axis=0, value=value) if row_pad else x
+
+    if space == "hbm":
+        mask_i = mask.astype(jnp.int32)
+        inc = jnp.cumsum(mask_i, axis=1)
+        counts = inc[:, -1]
+        off = jnp.where(mask, inc - mask_i, -1)
+        # scalar levels stay 2-D: a unit feature axis pads HBM tiles 128-fold
+        levels = [
+            tuple(rows(lvl if not item else flat(lvl, item)) for lvl in grp)
+            for grp, item in zip(bucket_groups, item_shapes)
+        ]
+        planes = [
+            common.pad_to(
+                rows(common.byte_planes(e if not item else flat(e, item), 1)),
+                common.MXU_LANE, axis=2,
+            )
+            for e, item in zip(elem_groups, item_shapes)
+        ]
+        outs = _kernel.push_back_hbm(
+            tuple(levels),
+            rows(sizes.astype(jnp.int32)),
+            rows(counts),
+            common.pad_to(rows(off, -1), common.MXU_LANE, axis=1, value=-1),
+            tuple(planes),
+            b0,
+            instrument=instrument,
+            interpret=run,
+        )
+        groups = outs[0] if instrument else outs
+        out_groups = tuple(
+            tuple(lvl[:nblocks].reshape(orig.shape) for lvl, orig in zip(grp, og))
+            for grp, og in zip(groups, bucket_groups)
+        )
+        new_sizes = sizes + counts
+        pos = jnp.where(mask, sizes[:, None].astype(jnp.int32) + inc - mask_i, -1)
+        if instrument:
+            m_pad = m + (-m) % common.MXU_LANE
+            pad_waste = (nblocks + row_pad) * m_pad - nblocks * m
+            vec = device.from_block(outs[1]) + device.pack(
+                **{"push_back.padded_lanes": pad_waste}
+            )
+            return out_groups, new_sizes, pos, vec
+        return out_groups, new_sizes, pos
+
+    dispatches = tuple(
+        common.resolve_dispatch(dispatch, m, e.dtype) for e in elem_groups
+    )
     buckets3 = [
-        tuple(flat(b, item) for b in grp)
+        tuple(rows(flat(b, item)) for b in grp)
         for grp, item in zip(bucket_groups, item_shapes)
     ]
-    elems3 = [flat(e, item) for e, item in zip(elem_groups, item_shapes)]
-    if row_pad:  # padded rows: mask all-False, sizes 0 — provably inert
-        buckets3 = [
-            tuple(common.pad_to(b, tile, axis=0) for b in grp) for grp in buckets3
-        ]
-        elems3 = [common.pad_to(e, tile, axis=0) for e in elems3]
-        mask = common.pad_to(mask, tile, axis=0)
-        sizes = common.pad_to(sizes, tile, axis=0)
-    elems3 = [common.pad_to(e, common.MXU_LANE, axis=1) for e in elems3]
-    mask = common.pad_to(mask, common.MXU_LANE, axis=1)
-
-    touch = (
-        _level_touch(sizes, mask.astype(jnp.int32), b0, nlev, tile)
-        if space == "hbm"
-        else None
-    )
+    elems3 = [
+        common.pad_to(rows(flat(e, item)), common.MXU_LANE, axis=1)
+        for e, item in zip(elem_groups, item_shapes)
+    ]
+    mask = common.pad_to(rows(mask), common.MXU_LANE, axis=1)
     outs = _kernel.push_back_pallas(
         tuple(buckets3),
-        sizes.reshape(-1, 1).astype(jnp.int32),
+        rows(sizes).reshape(-1, 1).astype(jnp.int32),
         b0,
         tuple(elems3),
         mask.astype(jnp.int32),
-        memory_space=space,
         dispatches=dispatches,
-        touch=touch,
         instrument=instrument,
-        interpret=common.should_interpret(interpret),
+        interpret=run,
     )
     groups, pos, new_sizes = outs[:3]
     out_groups = tuple(

@@ -56,7 +56,14 @@ def init_period_layers(key: jax.Array, cfg: ModelConfig, dtype) -> list[Param]:
     return slots
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def init_params(key: jax.Array, cfg: ModelConfig) -> Param:
+    """Random parameters from ``key``, built in one compiled program.
+
+    Under ``jit`` each draw fuses with its scale and dtype cast, so a
+    full-width model never holds a stacked f32 weight: peak memory is the
+    ``param_dtype`` parameters themselves.
+    """
     dtype = jnp.dtype(cfg.param_dtype)
     keys = jax.random.split(key, 4)
     params: Param = {

@@ -6,22 +6,12 @@ import os
 import subprocess
 import sys
 
-import jax.sharding
 import pytest
 
 WORKER = os.path.join(os.path.dirname(__file__), "_mesh_worker.py")
 SRC = os.path.join(os.path.dirname(__file__), "..", "..", "src")
 
-# launch/mesh.py builds meshes with explicit AxisType annotations, which the
-# container's jax 0.4.37 predates — pre-existing failures, green-or-skip here.
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable (container jax 0.4.37; "
-    "launch/mesh.py needs a newer jax)",
-)
 
-
-@needs_axis_type
 @pytest.mark.parametrize("arch", ["dbrx-132b", "qwen2.5-3b"])
 def test_sharded_train_step_matches_single_device(arch):
     env = dict(os.environ)
@@ -42,7 +32,6 @@ def test_sharded_train_step_matches_single_device(arch):
     assert result["param_max_diff"] < 5e-2, result
 
 
-@needs_axis_type
 def test_elastic_reshard_across_mesh_shapes(tmp_path):
     """Checkpoint saved under a (2,4) mesh restores bit-exactly onto (4,2)
     and (1,1) meshes — the elastic-scaling path."""

@@ -66,6 +66,27 @@ def test_resolve_memory_space_contract(monkeypatch):
         common.resolve_memory_space("smem")
 
 
+def test_vmem_tiling_refuses_compiled_mode():
+    """The vmem tilings are interpret-mode oracles: asking to compile one
+    (as a TPU run would) raises instead of rerouting to hbm in silence."""
+    pool = jnp.zeros((4, 4, 2), jnp.float32)
+    pages = jnp.zeros((2, 2), jnp.int32)
+    with pytest.raises(NotImplementedError, match="vmem tiling"):
+        paged_ops.paged_gather(pool, pages, memory_space="vmem", interpret=False)
+
+
+def test_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+    assert common.should_interpret(None) is False
+    assert common.should_interpret(False) is False
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        common.should_interpret(True)
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="REPRO_FORCE_INTERPRET"):
+        common.should_interpret(None)
+
+
 def test_resolve_dispatch_threshold():
     thr = common.MXU_DISPATCH_WAVE
     assert common.resolve_dispatch("auto", thr - 1, jnp.float32) == "onehot"
@@ -157,6 +178,29 @@ def test_slab_append_parity(space, dispatch, dtype):
 
 @pytest.mark.parametrize("space", SPACES)
 @pytest.mark.parametrize("dtype", DTYPES, ids=str)
+@pytest.mark.parametrize("S,T,N,P,m", [(14, 4, 4, 4, 5), (40, 8, 6, 5, 13), (5, 3, 2, 2, 4)])
+def test_scalar_pool_parity(space, dtype, S, T, N, P, m):
+    """Scalar items keep the pool 2-D (slabs in tiled rows): gather and
+    slab-append through the row-band path match the oracles."""
+    rng = np.random.default_rng(zlib.crc32(repr((space, str(dtype), S, T)).encode()))
+    pages = _fleet(rng, S, N, P, np.minimum(rng.integers(0, P + 1, N), S // N))
+    owners, bases = _ownership(pages, S, T)
+    counts = (np.asarray(pages) >= 0).sum(axis=1)
+    sizes = jnp.asarray(rng.integers(0, counts * T + 1), jnp.int32)
+    pool = _values(rng, (S, T), dtype)
+    got = paged_ops.paged_gather(pool, pages, memory_space=space)
+    want = paged_ops.paged_gather(pool, pages, use_ref=True)
+    _assert_trees_equal(got, want, f"scalar gather {space} {dtype}")
+    elems = _values(rng, (N, m), dtype)
+    mask = jnp.asarray(rng.random((N, m)) > 0.3)
+    args = (pool, owners, bases, sizes, elems, mask)
+    got = paged_ops.slab_append(*args, memory_space=space)
+    want = paged_ops.slab_append(*args, use_ref=True)
+    _assert_trees_equal(got, want, f"scalar slab_append {space} {dtype}")
+
+
+@pytest.mark.parametrize("space", SPACES)
+@pytest.mark.parametrize("dtype", DTYPES, ids=str)
 @pytest.mark.parametrize("nblocks,b0,nlev,m", [(5, 3, 3, 7), (8, 1, 4, 2), (3, 4, 2, 11)])
 def test_push_back_parity(space, dtype, nblocks, b0, nlev, m):
     rng = np.random.default_rng(
@@ -195,6 +239,29 @@ def test_flatten_parity(space, dtype, nblocks, b0, nlev):
         arr.buckets, arr.sizes, arr.b0, use_ref=True
     )
     _assert_trees_equal(got, want, f"flatten {space} {dtype}")
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=str)
+def test_hbm_windows_past_one_tile(dtype):
+    """Level rows wider than one hbm column chunk, and flattens spanning
+    several output tiles: the window, chunk and shift arithmetic of the
+    hbm tilings runs past its first tile and still matches the oracles."""
+    rng = np.random.default_rng(zlib.crc32(repr(("tiles", str(dtype))).encode()))
+    nblocks, b0, nlev, m = 11, 256, 6, 48  # levels 256 … 8192 slots
+    cap = indexing.capacity(b0, nlev)
+    arr = gg.init(nblocks, b0, dtype=dtype, nbuckets=nlev)
+    sizes = jnp.asarray(rng.integers(0, cap - m, nblocks), jnp.int32)
+    sizes = sizes.at[0].set(512 - 7)  # a wave across the 512-slot chunk edge
+    elems = _values(rng, (nblocks, m), dtype)
+    mask = jnp.asarray(rng.random((nblocks, m)) > 0.2)
+    args = (arr.buckets, sizes, b0, elems, mask)
+    got = pb_ops.push_back_fused(*args, memory_space="hbm")
+    want = pb_ops.push_back_fused(*args, use_ref=True)
+    _assert_trees_equal(got, want, f"push_back hbm tiles {dtype}")
+    levels, new_sizes, _ = want
+    got = flatten_ops.flatten_segmented(levels, new_sizes, b0, memory_space="hbm")
+    want = flatten_ops.flatten_segmented(levels, new_sizes, b0, use_ref=True)
+    _assert_trees_equal(got, want, f"flatten hbm tiles {dtype}")
 
 
 # --------------------------------------------------------------------------

@@ -246,9 +246,7 @@ def test_instrument_off_compiles_nothing_after_instrumented_runs():
     try:
         warm = BatchEngine(params, cfg, **kw).run_all(prompts, 3)
     finally:
-        from jax._src import monitoring as _mon
-
-        _mon._unregister_event_duration_listener_by_callback(spy)
+        jax.monitoring.unregister_event_duration_listener(spy)
     assert warm == first
     assert not compiles, (
         f"plain engine recompiled {len(compiles)} traces after an "

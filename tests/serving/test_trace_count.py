@@ -74,9 +74,7 @@ def test_second_engine_compiles_nothing():
     try:
         warm = BatchEngine(params, cfg, **kw).run_all(prompts, 3)
     finally:
-        from jax._src import monitoring as _mon
-
-        _mon._unregister_event_duration_listener_by_callback(spy)
+        jax.monitoring.unregister_event_duration_listener(spy)
     assert warm == first
     assert not compiles, (
         f"warm engine recompiled {len(compiles)} traces — the jit cache "

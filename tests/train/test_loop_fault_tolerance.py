@@ -50,11 +50,6 @@ def test_checkpoint_roundtrip_exact(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable (container jax 0.4.37; "
-    "launch/mesh.py needs a newer jax)",
-)
 def test_elastic_restore_onto_mesh_shardings(tmp_path):
     """A host-saved checkpoint restores under explicit (1,1) mesh shardings."""
     from repro.distributed import sharding as sh
