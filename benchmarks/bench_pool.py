@@ -66,7 +66,7 @@ def _serve(params, cfg, prompts, new_tokens, max_batch, admission, grow_chunk=1)
     t0 = time.perf_counter()
     be.run()
     dt = time.perf_counter() - t0
-    ttfts = [be._requests[r].ttft for r in rids]
+    ttfts = [be._requests[r].first_token_dispatch for r in rids]
     return be, dt, ttfts
 
 
@@ -221,7 +221,7 @@ def main() -> None:
     bp = BatchEngine(params, cfg, max_batch=max_batch, prefix_cache=True)
     r_cold = bp.submit(list(sys_prompt), new_tokens)
     bp.run()
-    ttft_cold = bp._requests[r_cold].ttft
+    ttft_cold = bp._requests[r_cold].first_token_dispatch
     chunks_cold = bp.stats.prefill_chunks
     hits0 = bp.stats.prefix_hits
     for _ in range(fleet_n):
@@ -233,7 +233,7 @@ def main() -> None:
     # against the cold request that ran alone through the same jit cache
     r_hit = bp.submit(list(sys_prompt), new_tokens)
     bp.run()
-    ttft_hit = bp._requests[r_hit].ttft
+    ttft_hit = bp._requests[r_hit].first_token_dispatch
     ttft_hit_ratio = ttft_hit / max(ttft_cold, 1e-12)
     emit(
         "pool_prefix_hit_rate",

@@ -26,7 +26,8 @@ gated too:
 The telemetry artifact (``METRICS_pool.json``, registry snapshots of the
 timed engines — ISSUE 8) supplies two more self-normalizing gates:
 
-* ``ttft_p95_ratio`` — chunked ``serve.ttft_ms`` p95 over monolithic p95,
+* ``ttft_p95_ratio`` — chunked ``serve.first_token_dispatch_ms`` p95 over
+  monolithic p95 (host dispatch of the first token's sample),
   both from the same process; chunked admission exists to cut tail TTFT,
   so this ratio drifting *up* past
   ``max((1 + tolerance) · baseline, 0.5)`` fails (the absolute ceiling
@@ -86,8 +87,9 @@ def _telemetry(path: str) -> tuple[float, float, float, float, float] | str:
         with open(path) as f:
             engines = json.load(f)["engines"]
         chunked, mono = engines["chunked"], engines["monolithic"]
-        ttft_ratio = chunked["histograms"]["serve.ttft_ms"]["p95"] / max(
-            mono["histograms"]["serve.ttft_ms"]["p95"], 1e-12
+        first = "serve.first_token_dispatch_ms"
+        ttft_ratio = chunked["histograms"][first]["p95"] / max(
+            mono["histograms"][first]["p95"], 1e-12
         )
         util = chunked["gauges"]["pool.live_tokens"]["hwm"] / max(
             chunked["gauges"]["pool.capacity_tokens"]["hwm"], 1

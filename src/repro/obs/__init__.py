@@ -1,8 +1,9 @@
 """Unified telemetry layer: metrics registry, span tracing, serving timeline.
 
 The observability subsystem the stats surfaces are rewired onto
-(DESIGN.md §9): ``Engine``/``BatchEngine`` (TTFT, TPOT, queue wait, chunk
-counts, admit/complete/starvation events), ``SlabArena``/``ExtentPool``
+(DESIGN.md §9): ``Engine``/``BatchEngine`` (host dispatch times of first
+tokens and decode steps, queue wait, chunk counts, admit/complete/starvation
+events, spans of each step), ``SlabArena``/``ExtentPool``
 (grow events, copied bytes, utilization), ``TwoPhasePipeline`` (freeze/thaw
 latency, elements frozen), ``CapacityPlanner``/``TenantPlanner`` (host
 contacts, via ``gauge_fn`` callbacks).  The legacy ``EngineStats``/

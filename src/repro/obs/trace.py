@@ -6,12 +6,17 @@
 * **spans** — ``with tracer.span("prefill_chunk", request=rid):`` wall-clock
   intervals.  Spans nest via the context-manager stack, which is exactly the
   nesting Chrome's trace viewer reconstructs from ``ph: "X"`` duration
-  events on one thread track.
+  events on one thread track.  Each span has an ``id`` and its ``parent``'s
+  id (``None`` at the top), so the causal tree survives without the depth.
 * **instants** — ``tracer.event("admit", request=rid)`` point events
   (``ph: "i"``), the serving timeline's admit/evict/starvation markers.
 * **counter samples** — ``tracer.sample("pool.utilization", 0.93)`` time
   series (``ph: "C"``), rendered as stacked graphs in the viewer — the
   per-step gauge track of the serving timeline.
+
+Times are offsets from ``Tracer.epoch``, the clock's reading when the tracer
+was made: ``epoch + t0_us * 1e-6`` puts a span on ``time.perf_counter``,
+the clock a profiler capture can be aligned to.
 
 Recording is append-to-a-list: no device contact, no synchronization, so
 spans are safe around the decode hot loop (they time the *dispatch* path —
@@ -41,6 +46,8 @@ class Span:
     dur_us: float
     depth: int
     attrs: dict
+    id: int
+    parent: int | None  # id of the enclosing span
 
 
 def _annotation_ctx(name: str):
@@ -55,30 +62,33 @@ def _annotation_ctx(name: str):
 class Tracer:
     def __init__(self, *, jax_annotations: bool = False, clock=time.perf_counter):
         self._clock = clock
-        self._epoch = clock()
+        self.epoch = clock()  # seconds on ``clock``; span times are offsets from it
         self.jax_annotations = jax_annotations
         self.spans: list[Span] = []
         self.events: list[dict] = []
         self.samples: list[dict] = []
-        self._stack: list[str] = []
+        self._stack: list[int] = []  # ids of the open spans
+        self._next_id = 0
 
     def _now_us(self) -> float:
-        return (self._clock() - self._epoch) * 1e6
+        return (self._clock() - self.epoch) * 1e6
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
         t0 = self._now_us()
-        self._stack.append(name)
+        sid = self._next_id
+        self._next_id += 1
+        parent = self._stack[-1] if self._stack else None
+        self._stack.append(sid)
         ctx = _annotation_ctx(name) if self.jax_annotations else contextlib.nullcontext()
         try:
             with ctx:
                 yield self
         finally:
-            depth = len(self._stack) - 1
             self._stack.pop()
             self.spans.append(
                 Span(name=name, t0_us=t0, dur_us=self._now_us() - t0,
-                     depth=depth, attrs=attrs)
+                     depth=len(self._stack), attrs=attrs, id=sid, parent=parent)
             )
 
     def event(self, name: str, **attrs) -> None:

@@ -60,22 +60,28 @@ __all__ = ["Engine", "EngineStats", "BatchEngine", "BatchStats", "Request"]
 # Shared jit factories — keyed on the (hashable, frozen) ModelConfig, so
 # every engine instance serving the same config reuses one traced executable
 # instead of re-tracing per instance (``jax.jit`` caches per function object:
-# a per-engine ``functools.partial`` made warm-up engines useless).
+# a per-engine ``functools.partial`` made warm-up engines useless).  The
+# jitted functions are named, so XLA calls the programs ``jit_decode_step``
+# and ``jit_prefill_chunk`` (a partial would be ``jit__unknown``): profiler
+# traces find them by these names.
 # --------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
 def _decode_step_fn(cfg: ModelConfig):
-    return jax.jit(
-        functools.partial(steps.decode_step, cfg=cfg), donate_argnums=(2,)
-    )
+    def decode_step(params, token, caches, length, active=None):
+        return steps.decode_step(params, token, caches, length, cfg, active)
+
+    return jax.jit(decode_step, donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
 def _prefill_chunk_fn(cfg: ModelConfig):
-    return jax.jit(
-        functools.partial(steps.prefill_chunk, cfg=cfg),
-        donate_argnums=(2,), static_argnames=("first",),
-    )
+    def prefill_chunk(params, tokens, caches, slot, t0, live, pages_row, first=True):
+        return steps.prefill_chunk(
+            params, tokens, caches, slot, t0, live, pages_row, cfg, first
+        )
+
+    return jax.jit(prefill_chunk, donate_argnums=(2,), static_argnames=("first",))
 
 
 class _StatsView:
@@ -302,9 +308,11 @@ class Request:
     done: bool = False
     submit_t: float = 0.0  # host wall-clock at submit()
     queue_wait: float = 0.0  # submit → admission (seconds)
-    ttft: float = 0.0  # submit → first sampled token (dispatch wall-clock)
-    decode_s: float = 0.0  # wall-clock spent in decode steps this req was in
-    tpot_ms: float = 0.0  # decode_s / (generated − 1), set at completion
+    # host dispatch times: JAX is asynchronous, so these time the enqueue of
+    # the work, not the device producing the token
+    first_token_dispatch: float = 0.0  # submit → first token's sample dispatched (s)
+    decode_s: float = 0.0  # host time dispatching the decode steps this req was in
+    decode_dispatch_ms_per_token: float = 0.0  # decode_s / (generated − 1), at completion
 
 
 class BatchStats(_StatsView):
@@ -644,14 +652,17 @@ class BatchEngine:
         self.obs.event("admit", rid=req.rid, slot=slot)
 
     def _note_first_token(self, req: Request) -> None:
-        """Record TTFT exactly once; the histogram sample and the timeline
-        event carry the same float, so the acceptance test reconciles them
-        by equality, not tolerance."""
-        req.ttft = time.time() - req.submit_t
+        """Record the first token's host dispatch time exactly once; the
+        histogram sample and the timeline event carry the same float, so the
+        acceptance test reconciles them by equality, not tolerance.  The
+        token is not ready yet (JAX is asynchronous): this is no TTFT."""
+        req.first_token_dispatch = time.time() - req.submit_t
+        ms = req.first_token_dispatch * 1e3
         self.obs.registry.histogram(
-            "serve.ttft_ms", "submit → first sampled token (dispatch)"
-        ).observe(req.ttft * 1e3, rid=req.rid)
-        self.obs.event("first_token", rid=req.rid, ttft_ms=req.ttft * 1e3)
+            "serve.first_token_dispatch_ms",
+            "submit → first token's sample dispatched (host dispatch)",
+        ).observe(ms, rid=req.rid)
+        self.obs.event("first_token", rid=req.rid, first_token_dispatch_ms=ms)
 
     # ---- cache construction ---------------------------------------------
     def _init_caches(self) -> list:
@@ -966,10 +977,11 @@ class BatchEngine:
         self._slots[req.slot] = None
         self.obs.registry.counter("serve.completed").inc()
         if req.generated > 1:
-            req.tpot_ms = req.decode_s / (req.generated - 1) * 1e3
+            req.decode_dispatch_ms_per_token = req.decode_s / (req.generated - 1) * 1e3
             self.obs.registry.histogram(
-                "serve.tpot_ms", "mean decode wall-clock per output token"
-            ).observe(req.tpot_ms, rid=req.rid)
+                "serve.decode_dispatch_ms_per_token",
+                "mean host dispatch time of the decode steps per output token",
+            ).observe(req.decode_dispatch_ms_per_token, rid=req.rid)
         self.obs.event("complete", rid=req.rid, generated=req.generated)
 
     # ---- chunked admission ----------------------------------------------
@@ -1088,7 +1100,7 @@ class BatchEngine:
         """Fully cached prompt: zero prefill chunks.  Publish the aliased
         pages to the device table and arm decode on the *last* prompt token
         (its K/V rewrite COWs the tail slab) — the request's first token
-        comes from the first decode step, where TTFT is recorded.
+        comes from the first decode step, where its dispatch time is recorded.
         """
         Lp = len(req.prompt)
         npages = int(self.book.npages[slot])
@@ -1184,16 +1196,26 @@ class BatchEngine:
         Any failure inside the step dumps a flight-recorder postmortem
         bundle (event ring + engine state + drained counters) before the
         exception propagates — DESIGN.md §9.y.
+
+        The step is the root span ``engine.step`` (``step``: the index its
+        decode step would take in the stream).  Its children cover the host
+        work between dispatches: ``engine.admit`` (admission and chunk
+        planning), ``prefill_chunk``, ``engine.grow`` (claims and pool
+        growth for the slots about to overflow), ``engine.cow`` (the
+        copy-on-write guard), ``decode_step`` and ``engine.finish``
+        (everything after the decode dispatch).
         """
         try:
-            return self._step_inner()
+            with self.obs.span("engine.step", step=len(self._stream)):
+                return self._step_inner()
         except BaseException as e:
             self._flight_dump("step_failure", e)
             raise
 
     def _step_inner(self) -> bool:
-        self._admit_pending()
-        tasks = self.sched.next_chunks() if self.sched is not None else []
+        with self.obs.span("engine.admit"):
+            self._admit_pending()
+            tasks = self.sched.next_chunks() if self.sched is not None else []
         for task in tasks:
             self._run_chunk(task)
         if self.sched is not None:
@@ -1210,24 +1232,26 @@ class BatchEngine:
         # needy slot this step (per-slot grows would fire once per sequence
         # under synchronized overflow — the double-grow the tests assert
         # against).
-        needy = [
-            r.slot
-            for r in active
-            if self._len_host[r.slot] + 1 > self.book.npages[r.slot] * self.T
-        ]
-        if needy:
-            short = self.book.shortfall(len(needy))
-            if short:
-                self._grow_for(short)
-            for slot in needy:
-                self._claim(slot, 1)
+        with self.obs.span("engine.grow"):
+            needy = [
+                r.slot
+                for r in active
+                if self._len_host[r.slot] + 1 > self.book.npages[r.slot] * self.T
+            ]
+            if needy:
+                short = self.book.shortfall(len(needy))
+                if short:
+                    self._grow_for(short)
+                for slot in needy:
+                    self._claim(slot, 1)
         # copy-on-write guard: the slab each slot is about to append into
         # must be private (a full-hit admission decodes its last prompt
         # token into the shared tail slab — copy it first, never mutate)
-        for req in active:
-            pos = int(self._len_host[req.slot])
-            if pos // self.T < int(self.book.npages[req.slot]):
-                self._cow_if_shared(req.slot, pos // self.T)
+        with self.obs.span("engine.cow"):
+            for req in active:
+                pos = int(self._len_host[req.slot])
+                if pos // self.T < int(self.book.npages[req.slot]):
+                    self._cow_if_shared(req.slot, pos // self.T)
         if self.sched is not None and self.sched.prefilling:
             act = np.zeros((self.B,), bool)
             act[[r.slot for r in active]] = True
@@ -1252,30 +1276,31 @@ class BatchEngine:
             self.key, k = jax.random.split(self.key)
             sampled = sample(k, logits, 0.0)
         step_dt = time.perf_counter() - step_t0
-        self._stream.append(sampled)
-        self.cur_tok = sampled
-        mask = np.zeros((self.B,), np.int32)
-        for req in active:
-            mask[req.slot] = 1
-        self.lengths = self.lengths + jnp.asarray(mask)
-        self._len_host += mask
-        self.obs.registry.counter("serve.decode_steps").inc()
-        self._sample_live()
-        stops = None
-        if self.stop_token is not None:
-            # one (B,) read per step — the price of stop-token scheduling
-            stops = np.asarray(self._host_read(sampled, "stop_drain"))
-        for req in active:
-            first_decode = req.generated == 0  # full-hit: first token is here
-            req.generated += 1
-            req.decode_s += step_dt
-            if first_decode:
-                req.first_tok = sampled[req.slot]
-                req.admit_step = len(self._stream)
-                self._note_first_token(req)
-            hit_stop = stops is not None and stops[req.slot] == self.stop_token
-            if req.generated >= req.max_new_tokens or hit_stop:
-                self._complete(req)
+        with self.obs.span("engine.finish"):
+            self._stream.append(sampled)
+            self.cur_tok = sampled
+            mask = np.zeros((self.B,), np.int32)
+            for req in active:
+                mask[req.slot] = 1
+            self.lengths = self.lengths + jnp.asarray(mask)
+            self._len_host += mask
+            self.obs.registry.counter("serve.decode_steps").inc()
+            self._sample_live()
+            stops = None
+            if self.stop_token is not None:
+                # one (B,) read per step — the price of stop-token scheduling
+                stops = np.asarray(self._host_read(sampled, "stop_drain"))
+            for req in active:
+                first_decode = req.generated == 0  # full-hit: first token is here
+                req.generated += 1
+                req.decode_s += step_dt
+                if first_decode:
+                    req.first_tok = sampled[req.slot]
+                    req.admit_step = len(self._stream)
+                    self._note_first_token(req)
+                hit_stop = stops is not None and stops[req.slot] == self.stop_token
+                if req.generated >= req.max_new_tokens or hit_stop:
+                    self._complete(req)
         return True
 
     def _has_work(self) -> bool:

@@ -4,6 +4,12 @@ Both walk the same period-scanned layer stack as training; the cache pytree
 rides the scan as xs/ys so its leaves carry the (n_periods, ...) stacking.
 ``decode_step`` is the ``serve_step`` the decode_32k / long_500k dry-run
 cells lower: one new token against a cache filled to seq_len.
+
+Each stage of the ``prefill_chunk`` and ``decode_step`` bodies runs under a
+``jax.named_scope`` (``SCOPES``), which only adds metadata: the scope is an
+element of every operation's ``op_name``, so a profiler trace can sum the
+device time of each stage.  A trace reduction groups by these names, so a
+rename is a change of yardstick.
 """
 from __future__ import annotations
 
@@ -26,12 +32,19 @@ from repro.obs import device
 from repro.serving import kvcache
 
 __all__ = [
+    "SCOPES",
     "prefill",
     "prefill_chunk",
     "decode_step",
     "init_decode_caches",
     "logits_from_hidden",
 ]
+
+
+SCOPES = (
+    "embed", "norm", "qkv", "pool_read", "kv_append", "attend", "out_proj",
+    "mlp", "pool_write", "lm_head",
+)
 
 
 def logits_from_hidden(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -45,11 +58,13 @@ def logits_from_hidden(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def _mlp_or_moe(sp, x, slot, cfg):
-    h = rms_norm(x, sp["norm2"], cfg.norm_eps)
-    if cfg.is_moe_layer(slot):
-        out, _ = moe_block(sp["moe"], h, cfg)
-        return x + out
-    return x + mlp_block(sp["mlp"], h, cfg.activation)
+    with jax.named_scope("norm"):
+        h = rms_norm(x, sp["norm2"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if cfg.is_moe_layer(slot):
+            out, _ = moe_block(sp["moe"], h, cfg)
+            return x + out
+        return x + mlp_block(sp["mlp"], h, cfg.activation)
 
 
 # Cache slots ride the period scan with a leading (n_periods, …) stacking;
@@ -171,7 +186,8 @@ def prefill_chunk(
     grid (``state=None`` → ``Q = min(chunk_size, L)``), and skips the prefix
     walk outright (every prefix lane is dead at t0 = 0).
     """
-    x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
     Cb = tokens.shape[1]
     positions = (t0 + jnp.arange(Cb))[None, :]  # (1, Cb) global positions
 
@@ -188,8 +204,10 @@ def prefill_chunk(
         with scope as t:
             for lslot, kind in enumerate(cfg.layout):
                 sp = period_params[lslot]
-                c = _cache_get(caches[lslot], idx)
-                h = rms_norm(x, sp["norm1"], cfg.norm_eps)
+                with jax.named_scope("pool_read"):
+                    c = _cache_get(caches[lslot], idx)
+                with jax.named_scope("norm"):
+                    h = rms_norm(x, sp["norm1"], cfg.norm_eps)
                 if kind == "mamba":
                     st = (
                         None
@@ -213,14 +231,19 @@ def prefill_chunk(
                         idx,
                     )
                     continue
-                q, k, v = project_qkv(sp["attn"], h, cfg, positions)
-                att = kvcache.chunk_attend(
-                    c, pages_row, q, k, v, t0, live, cfg, first=first
-                )
-                x = x + project_out(sp["attn"], att)
-                c2 = kvcache.scatter_chunk(c, pages_row, k, v, t0, live, cfg)
+                with jax.named_scope("qkv"):
+                    q, k, v = project_qkv(sp["attn"], h, cfg, positions)
+                with jax.named_scope("attend"):
+                    att = kvcache.chunk_attend(
+                        c, pages_row, q, k, v, t0, live, cfg, first=first
+                    )
+                with jax.named_scope("out_proj"):
+                    x = x + project_out(sp["attn"], att)
+                with jax.named_scope("kv_append"):
+                    c2 = kvcache.scatter_chunk(c, pages_row, k, v, t0, live, cfg)
                 x = _mlp_or_moe(sp, x, lslot, cfg)
-                caches[lslot] = _cache_put(caches[lslot], c2, idx)
+                with jax.named_scope("pool_write"):
+                    caches[lslot] = _cache_put(caches[lslot], c2, idx)
         if cfg.instrument:
             return (x, caches, ctr + t.total()), None
         return (x, caches), None
@@ -237,8 +260,9 @@ def prefill_chunk(
             (x, list(caches)),
             (params["layers"], jnp.arange(cfg.n_periods)),
         )
-    last = jax.lax.dynamic_index_in_dim(x[0], live - 1, 0, keepdims=False)
-    logits = logits_from_hidden(params, last[None], cfg)
+    with jax.named_scope("lm_head"):
+        last = jax.lax.dynamic_index_in_dim(x[0], live - 1, 0, keepdims=False)
+        logits = logits_from_hidden(params, last[None], cfg)
     if cfg.instrument:
         return logits, new_caches, ctr
     return logits, new_caches
@@ -301,7 +325,8 @@ def decode_step(
     periods — device data, no transfer.
     """
     token = token.reshape(token.shape[0], 1)
-    x = embed(params["embed"], token).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], token).astype(jnp.dtype(cfg.dtype))
     B = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
     positions = pos[:, None]  # (B, 1)
@@ -322,8 +347,10 @@ def decode_step(
         with scope as t:
             for slot, kind in enumerate(cfg.layout):
                 sp = period_params[slot]
-                c = _cache_get(caches[slot], idx)
-                h = rms_norm(x, sp["norm1"], cfg.norm_eps)
+                with jax.named_scope("pool_read"):
+                    c = _cache_get(caches[slot], idx)
+                with jax.named_scope("norm"):
+                    h = rms_norm(x, sp["norm1"], cfg.norm_eps)
                 if kind == "mamba":
                     y, st = ssm_mod.mamba_decode_step(
                         sp["mamba"], h, ssm_mod.MambaState(c["conv"], c["ssd"]), cfg
@@ -338,11 +365,15 @@ def decode_step(
                         caches[slot], {"conv": new_conv, "ssd": new_ssd}, idx
                     )
                     continue
-                q, k, v = project_qkv(sp["attn"], h, cfg, positions)
+                with jax.named_scope("qkv"):
+                    q, k, v = project_qkv(sp["attn"], h, cfg, positions)
                 kv_only = {key: val for key, val in c.items() if not key.startswith("cross")}
-                c2 = kvcache.append(kv_only, k, v, pos, cfg)
-                att = kvcache.attend(c2, q, pos + 1, cfg)
-                x = x + project_out(sp["attn"], att)
+                with jax.named_scope("kv_append"):
+                    c2 = kvcache.append(kv_only, k, v, pos, cfg)
+                with jax.named_scope("attend"):
+                    att = kvcache.attend(c2, q, pos + 1, cfg)
+                with jax.named_scope("out_proj"):
+                    x = x + project_out(sp["attn"], att)
                 if "cross_k" in c:
                     hc = rms_norm(x, sp["cross_norm"], cfg.norm_eps)
                     qc = jnp.einsum("bsd,dhk->bshk", hc, sp["cross"]["wq"])
@@ -353,7 +384,8 @@ def decode_step(
                     )
                     x = x + project_out(sp["cross"], attc)
                 x = _mlp_or_moe(sp, x, slot, cfg)
-                caches[slot] = _cache_put(caches[slot], c2, idx)
+                with jax.named_scope("pool_write"):
+                    caches[slot] = _cache_put(caches[slot], c2, idx)
         if cfg.instrument:
             return (x, caches, ctr + t.total()), None
         return (x, caches), None
@@ -370,7 +402,8 @@ def decode_step(
             (x, list(caches)),
             (params["layers"], jnp.arange(cfg.n_periods)),
         )
-    logits = logits_from_hidden(params, x[:, 0], cfg)
+    with jax.named_scope("lm_head"):
+        logits = logits_from_hidden(params, x[:, 0], cfg)
     if cfg.instrument:
         return logits, new_caches, ctr
     return logits, new_caches

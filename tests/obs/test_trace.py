@@ -1,6 +1,8 @@
 """Span tracer: nesting, instants, counter samples, JSON + Chrome export."""
 import json
 
+import pytest
+
 from repro.obs import ServingTimeline, Tracer
 
 
@@ -18,6 +20,21 @@ def test_spans_record_nesting_depth_and_attrs():
     assert outer.t0_us <= inner0.t0_us
     assert outer.dur_us >= inner0.dur_us
     assert inner0.attrs == {"rid": 1, "chunk": 0}
+    # the causal tree: ids are distinct, children name their parent
+    assert len({s.id for s in tr.spans}) == 3
+    assert outer.parent is None
+    assert [s.parent for s in tr.spans[:2]] == [outer.id, outer.id]
+
+
+def test_span_times_are_offsets_from_the_epoch():
+    ticks = iter([10.0, 10.5, 11.25])
+    tr = Tracer(clock=lambda: next(ticks))
+    with tr.span("s"):
+        pass
+    (s,) = tr.spans
+    assert tr.epoch == 10.0
+    assert tr.epoch + s.t0_us * 1e-6 == 10.5
+    assert s.dur_us == pytest.approx(0.75e6)
 
 
 def test_span_records_even_when_body_raises():

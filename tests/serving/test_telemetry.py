@@ -2,18 +2,26 @@
 the zero-sync contract on the decode hot path.
 
 Acceptance: a ``BatchEngine.run()`` over ≥ 8 ragged requests produces a
-timeline export (JSON + Chrome trace) whose per-request TTFT/TPOT and
+timeline export (JSON + Chrome trace) whose per-request dispatch times and
 per-step pool gauges reconcile **exactly** with the legacy ``BatchStats``
 view, and a transfer-guard test proves the instrumentation adds zero
-device→host transfers to the append/decode hot path.
+device→host transfers to the append/decode hot path.  The step programs
+carry their names and their stages' scopes into the lowered program, and
+the engine's spans form one tree per step on ``time.perf_counter``.
 """
 import json
+import re
+import time
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import reduced
 from repro.models import transformer
+from repro.serving import engine as engine_mod
+from repro.serving import steps
 from repro.serving.engine import BatchEngine, Engine
 
 from test_batch_engine import RAGGED_PROMPTS, _setup
@@ -47,18 +55,19 @@ def test_timeline_export_reconciles_with_legacy_stats(tmp_path):
     assert len(ev("pool_grow")) == be.stats.pool_grow_events
     assert counters["serve.decode_steps"] == be.stats.decode_steps
 
-    # per-request TTFT/TPOT: histogram series, timeline event, and the
+    # per-request dispatch times: histogram series, timeline event, and the
     # Request record all carry the same float (recorded once)
-    ttft = be.obs.registry.histogram("serve.ttft_ms")
-    tpot = be.obs.registry.histogram("serve.tpot_ms")
-    first_by_rid = {e["attrs"]["rid"]: e["attrs"]["ttft_ms"] for e in ev("first_token")}
+    first = be.obs.registry.histogram("serve.first_token_dispatch_ms")
+    per_token = be.obs.registry.histogram("serve.decode_dispatch_ms_per_token")
+    first_by_rid = {e["attrs"]["rid"]: e["attrs"]["first_token_dispatch_ms"]
+                    for e in ev("first_token")}
     for rid in rids:
         req = be._requests[rid]
-        assert ttft.values(rid=rid) == [req.ttft * 1e3]
-        assert first_by_rid[rid] == req.ttft * 1e3
-        assert req.ttft >= req.queue_wait >= 0
+        assert first.values(rid=rid) == [req.first_token_dispatch * 1e3]
+        assert first_by_rid[rid] == req.first_token_dispatch * 1e3
+        assert req.first_token_dispatch >= req.queue_wait >= 0
         if req.generated > 1:
-            assert tpot.values(rid=rid) == [req.tpot_ms]
+            assert per_token.values(rid=rid) == [req.decode_dispatch_ms_per_token]
 
     # per-step pool gauges ⇔ legacy peaks, and every utilization sample is
     # internally consistent (= live / capacity of the same instant)
@@ -116,7 +125,10 @@ def test_decode_hot_path_adds_zero_device_to_host_transfers(monkeypatch):
     assert calls == [], "decode hot path must not read the device"
     assert be.stats.decode_steps == steps_before + 5
     new_spans = be.obs.tracer.spans[spans_before:]
-    assert [s.name for s in new_spans] == ["decode_step"] * 5
+    # spans are appended as they close: each step's children, then its root
+    one_step = ["engine.admit", "engine.grow", "engine.cow", "decode_step",
+                "engine.finish", "engine.step"]
+    assert [s.name for s in new_spans] == one_step * 5
 
 
 def test_host_sync_audit_counts_every_device_get(monkeypatch):
@@ -267,3 +279,94 @@ def test_views_share_one_registry():
         snap["gauges"]["pool.live_tokens"]["hwm"] == be.stats.peak_live_tokens
     )
     assert be.stats._reg is be.obs.registry is be.sched.obs.registry
+
+
+# --------------------------------------------------------------------------
+# step programs: names and scopes; engine spans: one tree per step
+# --------------------------------------------------------------------------
+
+def _lowered(program: str):
+    """The engine's step program, lowered at the shapes of a small engine."""
+    cfg, params = _setup()
+    be = BatchEngine(params, cfg, max_batch=2, initial_slabs=4)
+    if program == "decode_step":
+        return engine_mod._decode_step_fn(cfg).lower(
+            be.params, be.cur_tok, be.caches, be.lengths, active=None)
+    row = np.full((be.book.max_pages,), -1, np.int32)
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    return engine_mod._prefill_chunk_fn(cfg).lower(
+        be.params, jnp.zeros((1, be.sched.C), jnp.int32), be.caches,
+        i32(0), i32(be.sched.C), i32(5), jnp.asarray(row), first=False)
+
+
+PROGRAMS = ["decode_step", "prefill_chunk"]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_step_programs_carry_their_names(program):
+    """XLA names the programs ``jit_<program>``: profiler traces and the
+    benchmark's trace reduction find them by these names."""
+    text = _lowered(program).as_text()
+    assert re.search(rf"module @jit_{program}\b", text)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_every_layer_op_falls_under_exactly_one_scope(program):
+    """Each stage of the step body runs under a named scope, which reaches
+    the compiled program as an element of every operation's ``op_name`` (a
+    profiler trace's ``tf_op``); inside the layer body (the scan's
+    ``closed_call``) each operation has exactly one."""
+    text = _lowered(program).compile().as_text()
+    paths = set(re.findall(rf'op_name="(jit\({program}\)/[^"]*)"', text))
+    seen = set()
+    for path in paths:
+        found = [p for p in path.split("/") if p in steps.SCOPES]
+        assert len(found) <= 1, path
+        if "/closed_call/" in path:
+            assert len(found) == 1, path
+        seen.update(found)
+    assert seen == set(steps.SCOPES)
+
+
+def test_engine_spans_nest_under_engine_step_on_perf_counter():
+    """Every span of a step descends from its ``engine.step`` root through
+    parent ids, lies inside its parent, and ``epoch + t0`` places it on
+    ``time.perf_counter``."""
+    cfg, params = _setup()
+    be = BatchEngine(params, cfg, max_batch=4)
+    for p in RAGGED_PROMPTS[:6]:
+        be.submit(p, 5)
+    tr = be.obs.tracer
+    windows = []
+    while be._has_work():
+        t0 = time.perf_counter()
+        be.step()
+        windows.append((t0, time.perf_counter()))
+    by_id = {s.id: s for s in tr.spans}
+    assert len(by_id) == len(tr.spans)
+    roots = sorted((s for s in tr.spans if s.parent is None), key=lambda s: s.t0_us)
+    assert [s.name for s in roots] == ["engine.step"] * len(windows)
+    for root, (lo, hi) in zip(roots, windows):
+        start = tr.epoch + root.t0_us * 1e-6
+        assert lo <= start <= start + root.dur_us * 1e-6 <= hi
+    children = {"engine.admit", "prefill_chunk", "engine.grow", "engine.cow",
+                "decode_step", "engine.finish"}
+    for s in tr.spans:
+        if s.parent is None:
+            continue
+        parent = by_id[s.parent]
+        assert parent.name == "engine.step" and s.name in children
+        assert s.depth == parent.depth + 1
+        assert parent.t0_us <= s.t0_us
+        assert s.t0_us + s.dur_us <= parent.t0_us + parent.dur_us
+    decoded = 0
+    for root in roots:
+        kids = sorted((s for s in tr.spans if s.parent == root.id), key=lambda s: s.t0_us)
+        names = [s.name for s in kids if s.name != "prefill_chunk"]
+        assert names in (["engine.admit"], ["engine.admit", "engine.grow", "engine.cow",
+                                            "decode_step", "engine.finish"])
+        if "decode_step" in names:
+            decoded += 1
+            assert root.attrs["step"] == next(
+                s for s in kids if s.name == "decode_step").attrs["step"]
+    assert decoded == be.stats.decode_steps > 0
