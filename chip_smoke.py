@@ -7,8 +7,9 @@ Phases, in order; any failure exits non-zero and prints no result:
 * pre-flight — the backend must be a TPU (there is no CPU fallback) and no
   interpret or memory-space override may be set in the environment;
 * A, serving — qwen2.5-3b at its published widths, parameters drawn from
-  ``--seed``, served through ``BatchEngine`` with its default options:
-  8 requests, prompts of 256–4096 tokens, 32 new tokens each;
+  ``--seed``, served through ``BatchEngine`` with its default options
+  (on a TPU the decode attend is then the Pallas kernel): 8 requests,
+  prompts of 256–4096 tokens, 32 new tokens each;
 * B, Pallas attend and extents — the same requests with
   ``paged_attend_impl="pallas"`` and ``grow_chunk="doubling"``: first-token
   logits must match phase A, the decode step must hold the compiled kernel

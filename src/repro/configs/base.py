@@ -89,9 +89,11 @@ class ModelConfig:
     cache_quant: bool = False  # int8 KV cache (per-token/head scales) — §Perf
     # paged policy (slab arena, DESIGN.md §4): tokens per slab (0 → cache_b0;
     # equality with cache_b0 is what makes the paged level walk bit-exact vs
-    # the ggarray bucket walk) and the attend implementation behind it
+    # the ggarray bucket walk) and the decode attend behind it: "auto" = the
+    # flash-decode kernel on a TPU for unquantised pools, the level walk on
+    # the CPU and for int8 pools (kvcache.resolve_paged_attend)
     cache_slab: int = 0
-    paged_attend_impl: Literal["levels", "pallas"] = "levels"
+    paged_attend_impl: Literal["auto", "levels", "pallas"] = "auto"
     # memory space for the indirection kernels (paged / push_back / flatten):
     # None = auto (hbm on TPU, vmem in interpret mode — kernels/common)
     kernel_memory_space: Literal["vmem", "hbm"] | None = None

@@ -2,7 +2,7 @@
 
 Three kernels back the arena subsystem (``repro.pool``, DESIGN.md §4), each
 built on the shared :class:`repro.kernels.common.GridPlan` memory-space layer
-(two tilings per kernel, one index math — DESIGN.md §4.7):
+(DESIGN.md §4.7; gather and append in two tilings, one index math):
 
 ``paged_gather_pallas``
     Materialize each logical array's contiguous view by walking its page
@@ -14,13 +14,16 @@ built on the shared :class:`repro.kernels.common.GridPlan` memory-space layer
     :func:`paged_gather_rows`, which DMAs each slab through its HBM row band.
 
 ``paged_attend_pallas``
-    Flash-decode attention against paged K/V pools: grid ``(batch, kv_heads,
-    pages)`` with the online-softmax state in VMEM scratch (the
-    ``kernels/decode_attention`` structure), the per-step KV tile selected by
-    the page table.  Pages past the live length — GGArray tail slabs — are
-    skipped entirely.  hbm: lengths and pages are scalar-prefetched and the
-    K/V ``index_map`` DMAs one ``(slab_tokens, head_dim)`` tile per step
-    instead of holding the pools resident.
+    Flash-decode attention read straight from the token-major ``(S, T, KH,
+    D)`` pool, every extent in one launch: grid ``(batch, blocks)``, where a
+    block is ``tile`` tokens of one slab with all KV heads side by side
+    (lane width ``KH·D``), and the online-softmax state of every query head
+    lives in VMEM scratch.  The queries enter block-diagonally, so one MXU
+    pass scores all heads and one more forms P·V.  One tiling, in both the
+    interpreter and Mosaic: the K/V ``index_map`` reads a scalar-prefetched
+    schedule (:func:`attend_schedule`) in which dead steps — pages past the
+    live length (GGArray tail slabs), unclaimed pages, idle rows — repeat
+    the previous block, so the pipeline skips their DMA.
 
 ``slab_append_pallas`` / ``slab_append_hbm``
     The push_back insert (exclusive mask scan + insert permutation, see
@@ -44,7 +47,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
-from repro.kernels.paged.ref import MASK_VALUE
+from repro.kernels.paged.ref import (
+    MASK_VALUE,
+    expand_queries,
+    head_diagonal,
+    split_halves,
+)
 from repro.kernels.push_back.kernel import apply_insert_permutation
 from repro.obs import device
 
@@ -53,35 +61,14 @@ __all__ = [
     "paged_gather_pallas_extents",
     "paged_gather_rows",
     "paged_attend_pallas",
-    "paged_attend_pallas_extents",
+    "attend_tile",
+    "attend_schedule",
     "slab_append_pallas",
     "slab_append_hbm",
     "DEFAULT_ROW_TILE",
 ]
 
 DEFAULT_ROW_TILE = 8
-
-
-def _attend_ctr(ctr_ref, slab_live, kv_len, p, slab_tokens):
-    """Accumulate one attend grid step's device counters (§9.x).
-
-    ``visit`` mirrors the body's compute gate exactly — live slab id AND
-    page start inside the KV length; ``masked_lanes`` counts score lanes in
-    *visited* tiles that the causal-length mask then discards (the tail
-    waste of token-granularity slabs).
-    """
-    visit = jnp.where(slab_live & (p * slab_tokens < kv_len), 1, 0)
-    masked = visit * (
-        slab_tokens - jnp.clip(kv_len - p * slab_tokens, 0, slab_tokens)
-    )
-    first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0) & (p == 0)
-    device.ctr_accum(ctr_ref, first, [
-        ("paged_attend.launches", jnp.where(first, 1, 0)),
-        ("paged_attend.tiles", visit),
-        ("paged_attend.tiles_skipped", 1 - visit),
-        ("paged_attend.lanes", visit * slab_tokens),
-        ("paged_attend.masked_lanes", masked),
-    ])
 
 
 # --------------------------------------------------------------------------
@@ -372,333 +359,203 @@ def paged_gather_pallas_extents(
 
 
 # --------------------------------------------------------------------------
-# attend — flash-decode through the page table.
+# attend — flash-decode through the page table, on the token-major pool.
 # --------------------------------------------------------------------------
 
-def _attend_step(q, k, v, kv_len, p, slab_tokens, m_ref, l_ref, acc_ref):
-    """One page's online-softmax update — shared by both memory spaces."""
-    s = jnp.dot(q, k.astype(jnp.float32).T, preferred_element_type=jnp.float32)
-    kpos = p * slab_tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+# Bytes of one K (or V) block.  The pipeline double-buffers K and V, so the
+# blocks take 4× this of VMEM — well inside the default scoped limit.
+ATTEND_BLOCK_BYTES = 1 << 20
+
+
+def attend_tile(slab_tokens: int, width: int, dtype) -> int:
+    """Tokens per attend block: the whole slab, halved while a
+    ``(tile, width)`` block exceeds :data:`ATTEND_BLOCK_BYTES` and the half
+    still fills whole sublane tiles (chat's 2 × 128 heads keep the 2048-token
+    slab; 16 × 64 heads take 512-token blocks)."""
+    tile = slab_tokens
+    row = width * jnp.dtype(dtype).itemsize
+    while tile * row > ATTEND_BLOCK_BYTES and tile % (2 * common.tile_rows(dtype)) == 0:
+        tile //= 2
+    return tile
+
+
+def attend_schedule(ext_tbl, off_tbl, lengths, n_sub: int, tile: int, n_ext: int):
+    """→ ``(step_ext (B·J,), fetch (E·B·J,))`` int32 for the ``(B, J)`` grid,
+    ``J = pages · n_sub``.
+
+    ``step_ext`` is the extent holding grid step ``(b, j)``'s block, or −1
+    when the step is dead (page unclaimed, or its first token at or past
+    the row's length).  ``fetch[e]`` is the block of extent ``e`` that step
+    maps to: its own on a live step of ``e``, else that of the last live
+    step of ``e`` before it (the first after it, for leading steps).  Between
+    live steps every index map therefore repeats the previous step's block,
+    and the pipeline moves no bytes for dead steps or idle rows.
+    """
+    B, P = ext_tbl.shape
+    J = P * n_sub
+    j = jnp.arange(J, dtype=jnp.int32)
+    ext = ext_tbl[:, j // n_sub]
+    live = (ext >= 0) & (j[None, :] * tile < lengths[:, None])
+    step_ext = jnp.where(live, ext, -1).reshape(-1)
+    blk = (off_tbl[:, j // n_sub] * n_sub + j % n_sub).reshape(-1)
+    order = jnp.arange(B * J, dtype=jnp.int32)
+    fetch = []
+    for e in range(n_ext):
+        mine = step_ext == e
+        last = jax.lax.cummax(jnp.where(mine, order, -1))
+        src = jnp.where(last >= 0, last, jnp.argmax(mine))
+        fetch.append(jnp.where(jnp.any(mine), blk[src], 0))
+    return step_ext, jnp.concatenate(fetch)
+
+
+def _attend_update(q2, k, v, kv_len, t0, m_ref, l_ref, acc_ref):
+    """One K/V block's online-softmax update, every query row at once.
+
+    ``q2`` ``(2R, F)`` stacks the two halves of the block-diagonal queries
+    (:func:`ref.expand_queries`); ``k``/``v`` ``(tile, F)`` are the pool's
+    rows as stored, all heads side by side.  Scores and P·V are MXU passes
+    in the pool's dtype with f32 accumulation; the halves sum to f32.
+    """
+    R = q2.shape[0] // 2
+    s2 = jax.lax.dot_general(
+        q2, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    s = s2[:R] + s2[R:]  # (R, tile)
+    kpos = t0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(kpos < kv_len, s, MASK_VALUE)
     m_prev, l_prev = m_ref[...], l_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     pw = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_prev * alpha + jnp.sum(pw, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        pw, v.astype(jnp.float32), preferred_element_type=jnp.float32
-    )
+    pv2 = jnp.dot(split_halves(pw, v.dtype, 0), v, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + (pv2[:R] + pv2[R:])
     m_ref[...] = m_new
 
 
-def _attend_vmem(
-    len_ref, pages_ref, q_ref, k_ref, v_ref, o_ref, *rest,
-    slab_tokens, n_pages, instrument=False,
+def _attend_body(
+    len_ref, ext_ref, fetch_ref, q_ref, *refs, n_ext, n_steps, tile, instrument,
 ):
+    ks, vs = refs[:n_ext], refs[n_ext : 2 * n_ext]
+    o_ref = refs[2 * n_ext]
     if instrument:
-        ctr_ref, m_ref, l_ref, acc_ref = rest
+        ctr_ref, m_ref, l_ref, acc_ref = refs[2 * n_ext + 1 :]
     else:
-        m_ref, l_ref, acc_ref = rest
-    p = pl.program_id(2)
+        m_ref, l_ref, acc_ref = refs[2 * n_ext + 1 :]
+    b, j = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kv_len = len_ref[0, 0]
-    slab = pages_ref[0, p]
-
-    @pl.when((slab >= 0) & (p * slab_tokens < kv_len))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        k = k_ref[0, pl.ds(jnp.maximum(slab, 0), 1)][0]  # (T, D)
-        v = v_ref[0, pl.ds(jnp.maximum(slab, 0), 1)][0]
-        _attend_step(q, k, v, kv_len, p, slab_tokens, m_ref, l_ref, acc_ref)
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-    if instrument:
-        _attend_ctr(ctr_ref, slab >= 0, kv_len, p, slab_tokens)
-
-
-def _attend_hbm(
-    len_ref, pages_ref, q_ref, k_ref, v_ref, o_ref, *rest,
-    slab_tokens, n_pages, instrument=False,
-):
-    if instrument:
-        ctr_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        m_ref, l_ref, acc_ref = rest
-    b, p = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(p == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kv_len = len_ref[b]
-    slab = pages_ref[b, p]
+    e = ext_ref[b * n_steps + j]  # −1: dead step, its blocks are the last ones
+    t0 = j * tile
+    for i in range(n_ext):
 
-    @pl.when((slab >= 0) & (p * slab_tokens < kv_len))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        # this step's DMA'd tiles: k/v_pool[head, pages[b, p]]
-        _attend_step(
-            q, k_ref[0, 0], v_ref[0, 0], kv_len, p, slab_tokens,
-            m_ref, l_ref, acc_ref,
-        )
+        @pl.when(e == i)
+        def _(i=i):
+            _attend_update(
+                q_ref[0], ks[i][0], vs[i][0], kv_len, t0, m_ref, l_ref, acc_ref
+            )
 
-    @pl.when(p == n_pages - 1)
+    @pl.when(j == n_steps - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     if instrument:
-        _attend_ctr(ctr_ref, slab >= 0, kv_len, p, slab_tokens)
+        visit = jnp.where(e >= 0, 1, 0)
+        first = (b == 0) & (j == 0)
+        device.ctr_accum(ctr_ref, first, [
+            ("paged_attend.launches", jnp.where(first, 1, 0)),
+            ("paged_attend.tiles", visit),
+            ("paged_attend.tiles_skipped", 1 - visit),
+            ("paged_attend.lanes", visit * tile),
+            ("paged_attend.masked_lanes",
+             visit * (tile - jnp.clip(kv_len - t0, 0, tile))),
+        ])
 
 
 def paged_attend_pallas(
     q: jax.Array,  # (B, KH, G, D) f32, pre-scaled
-    k_pool: jax.Array,  # (KH, S, T, D) head-major pool
-    v_pool: jax.Array,  # (KH, S, T, D)
-    pages: jax.Array,  # (B, P) int32
+    k_extents: tuple[jax.Array, ...],  # each (S_e, T, KH·D) token-major
+    v_extents: tuple[jax.Array, ...],
+    ext_tbl: jax.Array,  # (B, P) int32 — extent id per page, −1 unclaimed
+    off_tbl: jax.Array,  # (B, P) int32 — offset-in-extent per page
     lengths: jax.Array,  # (B,) int32
     *,
-    memory_space: str = "vmem",
+    tile: int,
     instrument: bool = False,
     interpret: bool = False,
 ):
+    """Flash-decode through the two-level page table → (B, KH, G, D) f32.
+
+    Each extent is read where it lies, viewed as ``(S_e·T/tile, tile,
+    KH·D)`` — a free reshape of the leading dims.  Grid ``(B, pages·T/tile)``: one step takes
+    one block of ``tile`` tokens with every KV head; its index map reads
+    the scalar-prefetched :func:`attend_schedule`.  With ``instrument=True``
+    → (out, counter block); a counted tile is one such step.
+    """
     B, KH, G, D = q.shape
-    _, S, T, _ = k_pool.shape
-    P = pages.shape[1]
-    pages = pages.astype(jnp.int32)
+    T = k_extents[0].shape[1]
+    F = KH * D
+    n_sub = T // tile
+    P = ext_tbl.shape[1]
+    J = P * n_sub
+    E = len(k_extents)
+    R = -(-(KH * G) // 16) * 16  # query rows, whole bf16 sublane tiles
     lengths = lengths.astype(jnp.int32)
-    scratch = [
-        pltpu.VMEM((G, 1), jnp.float32),
-        pltpu.VMEM((G, 1), jnp.float32),
-        pltpu.VMEM((G, D), jnp.float32),
-    ]
-    out_shape = jax.ShapeDtypeStruct((B, KH, G, D), jnp.float32)
-    if memory_space == "hbm":
-        kv_spec = pl.BlockSpec(
-            (1, 1, T, D),
-            lambda b, h, p, lens, pages: (h, jnp.clip(pages[b, p], 0, S - 1), 0, 0),
+    step_ext, fetch = attend_schedule(
+        ext_tbl.astype(jnp.int32), off_tbl.astype(jnp.int32), lengths,
+        n_sub, tile, E,
+    )
+    def view(x):
+        # pinned to HBM when compiled: the kernel DMAs the blocks it needs,
+        # so XLA must not prefetch a whole extent into VMEM for it
+        x = x.reshape(x.shape[0] * n_sub, tile, F)
+        return x if interpret else pltpu.with_memory_space_constraint(x, pltpu.HBM)
+
+    def kv_spec(e: int):
+        return pl.BlockSpec(
+            (1, tile, F),
+            lambda b, j, lens, ext, fetch: (fetch[(e * B + b) * J + j], 0, 0),
         )
-        plan = common.GridPlan(
-            memory_space="hbm",
-            grid=(B, KH, P),
-            num_tables=2,
-            table_specs=(),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, p, lens, pages: (b, h, 0, 0)),
-                kv_spec,
-                kv_spec,
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, D), lambda b, h, p, lens, pages: (b, h, 0, 0)
-            ),
-            scratch_shapes=scratch,
-            instrument=instrument,
-        )
-        kernel = functools.partial(
-            _attend_hbm, slab_tokens=T, n_pages=P, instrument=instrument
-        )
-        outs = plan.pallas_call(kernel, out_shape, interpret=interpret)(
-            lengths, pages, q, k_pool, v_pool
-        )
-        return (outs[0], outs[1]) if instrument else outs
+
+    row = lambda b, j, *_: (b, 0, 0)
     plan = common.GridPlan(
-        memory_space="vmem",
-        grid=(B, KH, P),
-        num_tables=2,
-        table_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, p: (b, 0)),
-            pl.BlockSpec((1, P), lambda b, h, p: (b, 0)),
-        ],
+        memory_space="hbm",
+        grid=(B, J),
+        num_tables=3,
+        table_specs=(),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, p: (b, h, 0, 0)),
-            pl.BlockSpec((1, S, T, D), lambda b, h, p: (h, 0, 0, 0)),
-            pl.BlockSpec((1, S, T, D), lambda b, h, p: (h, 0, 0, 0)),
+            pl.BlockSpec((1, 2 * R, F), row),
+            *[kv_spec(e) for e in range(E)],
+            *[kv_spec(e) for e in range(E)],
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, p: (b, h, 0, 0)),
-        scratch_shapes=scratch,
+        out_specs=pl.BlockSpec((1, R, F), row),
+        scratch_shapes=[
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, F), jnp.float32),
+        ],
         instrument=instrument,
     )
     kernel = functools.partial(
-        _attend_vmem, slab_tokens=T, n_pages=P, instrument=instrument
+        _attend_body, n_ext=E, n_steps=J, tile=tile, instrument=instrument
     )
-    outs = plan.pallas_call(kernel, out_shape, interpret=interpret)(
-        lengths.reshape(B, 1), pages, q, k_pool, v_pool
+    outs = plan.pallas_call(
+        kernel, jax.ShapeDtypeStruct((B, R, F), jnp.float32), interpret=interpret
+    )(
+        lengths, step_ext, fetch,
+        expand_queries(q, R, k_extents[0].dtype),
+        *map(view, k_extents), *map(view, v_extents),
     )
-    return (outs[0], outs[1]) if instrument else outs
-
-
-def _attend_vmem_extents(
-    len_ref, ext_ref, off_ref, q_ref, *refs, slab_tokens, n_pages, n_ext,
-):
-    ks, vs = refs[:n_ext], refs[n_ext : 2 * n_ext]
-    o_ref = refs[2 * n_ext]
-    m_ref, l_ref, acc_ref = refs[2 * n_ext + 1 :]
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kv_len = len_ref[0, 0]
-    ext = ext_ref[0, p]
-    off = off_ref[0, p]
-
-    @pl.when((ext >= 0) & (p * slab_tokens < kv_len))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        T, D = ks[0].shape[2:]
-        k = jnp.zeros((T, D), ks[0].dtype)
-        v = jnp.zeros((T, D), vs[0].dtype)
-        for e in range(n_ext):
-            row = common.extent_row(ext, off, e, ks[e].shape[1])
-            k = jnp.where(ext == e, ks[e][0, pl.ds(row, 1)][0], k)
-            v = jnp.where(ext == e, vs[e][0, pl.ds(row, 1)][0], v)
-        _attend_step(q, k, v, kv_len, p, slab_tokens, m_ref, l_ref, acc_ref)
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _attend_hbm_extents(
-    len_ref, ext_ref, off_ref, q_ref, *refs, slab_tokens, n_pages, n_ext,
-):
-    ks, vs = refs[:n_ext], refs[n_ext : 2 * n_ext]
-    o_ref = refs[2 * n_ext]
-    m_ref, l_ref, acc_ref = refs[2 * n_ext + 1 :]
-    b, p = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kv_len = len_ref[b]
-    ext = ext_ref[b, p]
-
-    @pl.when((ext >= 0) & (p * slab_tokens < kv_len))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        # each extent DMA'd one (T, D) tile; consume the one ``ext`` selects
-        k = jnp.zeros(ks[0][0, 0].shape, ks[0].dtype)
-        v = jnp.zeros(vs[0][0, 0].shape, vs[0].dtype)
-        for e in range(n_ext):
-            k = jnp.where(ext == e, ks[e][0, 0], k)
-            v = jnp.where(ext == e, vs[e][0, 0], v)
-        _attend_step(q, k, v, kv_len, p, slab_tokens, m_ref, l_ref, acc_ref)
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def paged_attend_pallas_extents(
-    q: jax.Array,  # (B, KH, G, D) f32, pre-scaled
-    k_extents: tuple[jax.Array, ...],  # each (KH, S_e, T, D) head-major
-    v_extents: tuple[jax.Array, ...],
-    ext_tbl: jax.Array,  # (B, P) int32 — extent id per page, −1 unclaimed
-    off_tbl: jax.Array,  # (B, P) int32
-    lengths: jax.Array,  # (B,) int32
-    *,
-    memory_space: str = "vmem",
-    interpret: bool = False,
-) -> jax.Array:
-    """Multi-extent ``paged_attend_pallas``: the K/V index_maps resolve the
-    page walk through the two-level (extent, offset) table."""
-    B, KH, G, D = q.shape
-    T = k_extents[0].shape[2]
-    P = ext_tbl.shape[1]
-    E = len(k_extents)
-    ext_tbl = ext_tbl.astype(jnp.int32)
-    off_tbl = off_tbl.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-    scratch = [
-        pltpu.VMEM((G, 1), jnp.float32),
-        pltpu.VMEM((G, 1), jnp.float32),
-        pltpu.VMEM((G, D), jnp.float32),
-    ]
-    out_shape = jax.ShapeDtypeStruct((B, KH, G, D), jnp.float32)
-    if memory_space == "hbm":
-        def kv_spec(e: int, size: int):
-            return pl.BlockSpec(
-                (1, 1, T, D),
-                lambda b, h, p, lens, ext, off: (
-                    h,
-                    common.extent_row(ext[b, p], off[b, p], e, size),
-                    0,
-                    0,
-                ),
-            )
-
-        plan = common.GridPlan(
-            memory_space="hbm",
-            grid=(B, KH, P),
-            num_tables=3,
-            table_specs=(),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, G, D), lambda b, h, p, lens, ext, off: (b, h, 0, 0)
-                ),
-                *[kv_spec(e, k.shape[1]) for e, k in enumerate(k_extents)],
-                *[kv_spec(e, v.shape[1]) for e, v in enumerate(v_extents)],
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, D), lambda b, h, p, lens, ext, off: (b, h, 0, 0)
-            ),
-            scratch_shapes=scratch,
-        )
-        kernel = functools.partial(
-            _attend_hbm_extents, slab_tokens=T, n_pages=P, n_ext=E
-        )
-        return plan.pallas_call(kernel, out_shape, interpret=interpret)(
-            lengths, ext_tbl, off_tbl, q, *k_extents, *v_extents
-        )
-    plan = common.GridPlan(
-        memory_space="vmem",
-        grid=(B, KH, P),
-        num_tables=3,
-        table_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, p: (b, 0)),
-            pl.BlockSpec((1, P), lambda b, h, p: (b, 0)),
-            pl.BlockSpec((1, P), lambda b, h, p: (b, 0)),
-        ],
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, p: (b, h, 0, 0)),
-            *[
-                pl.BlockSpec((1, k.shape[1], T, D), lambda b, h, p: (h, 0, 0, 0))
-                for k in k_extents
-            ],
-            *[
-                pl.BlockSpec((1, v.shape[1], T, D), lambda b, h, p: (h, 0, 0, 0))
-                for v in v_extents
-            ],
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, p: (b, h, 0, 0)),
-        scratch_shapes=scratch,
-    )
-    kernel = functools.partial(
-        _attend_vmem_extents, slab_tokens=T, n_pages=P, n_ext=E
-    )
-    return plan.pallas_call(kernel, out_shape, interpret=interpret)(
-        lengths.reshape(B, 1), ext_tbl, off_tbl, q, *k_extents, *v_extents
-    )
+    if instrument:
+        return head_diagonal(outs[0], KH, G), outs[1]
+    return head_diagonal(outs, KH, G)
 
 
 # --------------------------------------------------------------------------

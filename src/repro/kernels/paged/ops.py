@@ -5,8 +5,9 @@ the 3-D/4-D kernels (the ``kernels/push_back`` convention) and pad row/slab
 counts to the kernel tile with provably inert rows (page −1 / owner −1).
 ``use_ref=True`` runs the jnp oracle — bit-identical in interpret mode.
 
-``memory_space`` selects the kernel tiling (``common.resolve_memory_space``:
-explicit > ``REPRO_MEMORY_SPACE`` > hbm on TPU / vmem in interpret mode);
+``memory_space`` selects the gather and append tilings
+(``common.resolve_memory_space``: explicit > ``REPRO_MEMORY_SPACE`` > hbm on
+TPU / vmem in interpret mode; the attend has one tiling);
 ``slab_append``'s ``dispatch`` selects the insert-permutation backend
 (``common.resolve_dispatch`` — MXU matmul for wide waves).
 """
@@ -41,21 +42,24 @@ def _gather_ctr(table: jax.Array, space: str, row_tile: int) -> jax.Array:
     })
 
 
-def _attend_ctr(table: jax.Array, lengths: jax.Array, T: int, KH: int) -> jax.Array:
-    """jnp attend counters over a (B, P) liveness table — the per-(b, p)
-    walk the kernel grids over, times the KH head steps."""
-    B, P = table.shape
-    p_idx = jnp.arange(P, dtype=jnp.int32)[None, :]
+def _attend_ctr(ext_tbl: jax.Array, lengths: jax.Array, T: int, tile: int) -> jax.Array:
+    """jnp attend counters over a (B, P) liveness table — the per-(row,
+    block) walk the kernel grids over; one block holds every KV head."""
+    B, P = ext_tbl.shape
+    n_sub = T // tile
+    j = jnp.arange(P * n_sub, dtype=jnp.int32)
+    t0 = (j * tile)[None, :]
     kv = lengths.astype(jnp.int32)[:, None]
-    visit = ((table >= 0) & (p_idx * T < kv)).astype(jnp.int32)  # (B, P)
-    masked = visit * (T - jnp.clip(kv - p_idx * T, 0, T))
+    visit = ((ext_tbl[:, j // n_sub] >= 0) & (t0 < kv)).astype(jnp.int32)
     tiles = jnp.sum(visit)
     return device.pack(**{
         "paged_attend.launches": 1,
-        "paged_attend.tiles": KH * tiles,
-        "paged_attend.tiles_skipped": KH * (B * P - tiles),
-        "paged_attend.lanes": KH * tiles * T,
-        "paged_attend.masked_lanes": KH * jnp.sum(masked),
+        "paged_attend.tiles": tiles,
+        "paged_attend.tiles_skipped": B * P * n_sub - tiles,
+        "paged_attend.lanes": tiles * tile,
+        "paged_attend.masked_lanes": jnp.sum(
+            visit * (tile - jnp.clip(kv - t0, 0, tile))
+        ),
     })
 
 
@@ -142,64 +146,58 @@ def paged_gather(
     return out
 
 
-@partial(
-    jax.jit,
-    static_argnames=("interpret", "use_ref", "memory_space", "instrument"),
-)
+@partial(jax.jit, static_argnames=("interpret", "use_ref", "instrument", "tile"))
 def paged_attend(
     q: jax.Array,  # (B, KH, G, D) f32, pre-scaled
-    k_pool,  # (S, T, KH, D) token-major pool, or tuple of extents
-    v_pool,  # (S, T, KH, D) or tuple of extents
+    k_pool,  # (S, T, KH·D) token-major pool, or tuple of extents
+    v_pool,  # (S, T, KH·D) or tuple of extents
     pages: jax.Array,  # (B, P) int32 — global slab ids
     lengths: jax.Array,  # (B,) int32
     *,
     interpret: bool | None = None,
     use_ref: bool = False,
-    memory_space: str | None = None,
     instrument: bool = False,
+    tile: int | None = None,
 ) -> Any:
     """→ (B, KH, G, D) f32 attention output through the page table.
 
-    Pools arrive in the cache's token-major ``(slab, slot, head, dim)``
-    layout and are transposed head-major for the kernel's per-head blocking
-    (a production pool would be laid out head-major to begin with).  Tuple
-    pools are segmented extents; the walk resolves global slab ids through
-    the two-level (extent, offset) table.  ``instrument=True`` → (out,
-    device counter vector): in-kernel on the single-extent path, the
-    matching jnp oracle elsewhere.
+    Pools are read in place in the cache's own token-major ``(slab, slot,
+    head·dim)`` layout, each block of ``tile`` tokens (default
+    ``kernel.attend_tile``) with all heads at once.  A ``(S, T, KH, D)``
+    pool is flattened first — free on the CPU, a copy of the pool on a TPU,
+    whose tiled layout keeps a trailing ``(KH, D)`` pair apart (why the
+    engine stores its pools flat).  Tuple pools are
+    segmented extents, walked in the same launch through the two-level
+    (extent, offset) table.  The attend has one tiling, so no
+    ``memory_space``.  ``instrument=True`` → (out, device counter vector):
+    in-kernel, or the matching jnp oracle under ``use_ref``.
     """
-    k_exts = _as_extents(k_pool)
-    v_exts = _as_extents(v_pool)
-    kh = tuple(k.transpose(2, 0, 1, 3) for k in k_exts)  # each (KH, S_e, T, D)
-    vh = tuple(v.transpose(2, 0, 1, 3) for v in v_exts)
-    KH, T = kh[0].shape[0], kh[0].shape[2]
+    flat = lambda x: x.reshape(*x.shape[:2], -1)
+    k_exts = tuple(map(flat, _as_extents(k_pool)))
+    v_exts = tuple(map(flat, _as_extents(v_pool)))
+    _, KH, _, D = q.shape
+    T = k_exts[0].shape[1]
+    if tile is None:
+        tile = _kernel.attend_tile(T, KH * D, k_exts[0].dtype)
+    lengths = lengths.astype(jnp.int32)
+    ext_tbl, off_tbl = _extents.resolve_pages(
+        pages, tuple(k.shape[0] for k in k_exts)
+    )
     if use_ref:
-        k1 = kh[0] if len(kh) == 1 else jnp.concatenate(kh, axis=1)
-        v1 = vh[0] if len(vh) == 1 else jnp.concatenate(vh, axis=1)
-        out = _ref.attend_paged(q, k1, v1, pages, lengths)
-        if instrument:
-            return out, _attend_ctr(pages, lengths, T, KH)
-        return out
-    space = common.resolve_memory_space(memory_space, interpret)
-    run = common.should_interpret(interpret)
-    if len(kh) == 1:
-        outs = _kernel.paged_attend_pallas(
-            q, kh[0], vh[0], pages, lengths,
-            memory_space=space, instrument=instrument, interpret=run,
+        out = _ref.attend_paged(
+            q, _extents.flat_data(k_exts), _extents.flat_data(v_exts),
+            pages, lengths, tile,
         )
         if instrument:
-            return outs[0], device.from_block(outs[1])
-        return outs
-    ext_tbl, off_tbl = _extents.resolve_pages(
-        pages, tuple(k.shape[1] for k in kh)
-    )
-    out = _kernel.paged_attend_pallas_extents(
-        q, kh, vh, ext_tbl, off_tbl, lengths,
-        memory_space=space, interpret=run,
+            return out, _attend_ctr(ext_tbl, lengths, T, tile)
+        return out
+    outs = _kernel.paged_attend_pallas(
+        q, k_exts, v_exts, ext_tbl, off_tbl, lengths, tile=tile,
+        instrument=instrument, interpret=common.should_interpret(interpret),
     )
     if instrument:
-        return out, _attend_ctr(ext_tbl, lengths, T, KH)
-    return out
+        return outs[0], device.from_block(outs[1])
+    return outs
 
 
 def _touched_slabs(owners, bases, sizes, counts, T: int, m: int):

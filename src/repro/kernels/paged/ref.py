@@ -10,7 +10,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["gather_pages", "attend_paged", "slab_append", "MASK_VALUE"]
+__all__ = [
+    "gather_pages",
+    "attend_paged",
+    "expand_queries",
+    "head_diagonal",
+    "split_halves",
+    "slab_append",
+    "MASK_VALUE",
+]
 
 MASK_VALUE = -1e30  # matches models.attention.MASK_VALUE (serving softmax mask)
 
@@ -24,48 +32,96 @@ def gather_pages(pool: jax.Array, pages: jax.Array) -> jax.Array:
     return jnp.where(valid, out, 0).reshape(N, P * T, D)
 
 
-def attend_paged(
-    q: jax.Array,  # (B, KH, G, D) f32, pre-scaled
-    k_pool: jax.Array,  # (KH, S, T, D) — head-major pool layout
-    v_pool: jax.Array,  # (KH, S, T, D)
-    pages: jax.Array,  # (B, P) int32
-    lengths: jax.Array,  # (B,) int32 live tokens per sequence
-) -> jax.Array:
-    """One-token attention through the page table, page-at-a-time.
+def split_halves(x: jax.Array, dtype, axis: int) -> jax.Array:
+    """f32 ``x`` → ``[hi; lo]`` stacked on ``axis``, each in ``dtype``.
 
-    Online-softmax merge in page order — the flash-decode structure the
-    Pallas kernel runs per grid step.  A page past the live length (or an
-    unclaimed ``-1`` entry) leaves the state untouched, exactly like the
-    kernel's ``pl.when`` skip.
+    ``hi`` is ``x`` rounded to ``dtype`` and ``lo`` the rounded remainder,
+    so a matmul against values held in ``dtype`` (the pool's K/V) that sums
+    the two halves' products keeps ~16 bits of ``x``'s mantissa in bf16 —
+    every product exact, accumulated in f32 — with no upcast of the pool.
+    """
+    hi = x.astype(dtype)
+    lo = (x - hi.astype(jnp.float32)).astype(dtype)
+    return jnp.concatenate([hi, lo], axis=axis)
+
+
+def expand_queries(q: jax.Array, rows: int, dtype) -> jax.Array:
+    """q (B, KH, G, D) → (B, 2·rows, KH·D) block-diagonal query halves.
+
+    Row ``h·G + g`` holds ``q[h, g]`` in head ``h``'s ``D`` lanes and zeros
+    elsewhere, so one matmul against a ``(tokens, KH·D)`` block of the
+    token-major pool scores every head at once; rows past ``KH·G`` are zero
+    padding.  The rows are then :func:`split_halves` in ``dtype``.
     """
     B, KH, G, D = q.shape
-    S, T = k_pool.shape[1:3]
-    P = pages.shape[1]
-    m = jnp.full((B, KH, G), MASK_VALUE, jnp.float32)
-    l = jnp.zeros((B, KH, G), jnp.float32)
-    acc = jnp.zeros((B, KH, G, D), jnp.float32)
+    eye = jnp.eye(KH, dtype=q.dtype)
+    blk = (q[:, :, :, None, :] * eye[None, :, None, :, None]).reshape(
+        B, KH * G, KH * D
+    )
+    blk = jnp.pad(blk, ((0, 0), (0, rows - KH * G), (0, 0)))
+    return split_halves(blk, dtype, 1)
+
+
+def head_diagonal(out: jax.Array, KH: int, G: int) -> jax.Array:
+    """(B, rows, KH·D) products of every query row with every head's V →
+    (B, KH, G, D): each row's own head."""
+    B, _, F = out.shape
+    o = out[:, : KH * G].reshape(B, KH, G, KH, F // KH)
+    return jnp.diagonal(o, axis1=1, axis2=3).transpose(0, 3, 1, 2)
+
+
+def attend_paged(
+    q: jax.Array,  # (B, KH, G, D) f32, pre-scaled
+    k_pool: jax.Array,  # (S, T, KH·D) — token-major pool
+    v_pool: jax.Array,  # (S, T, KH·D)
+    pages: jax.Array,  # (B, P) int32
+    lengths: jax.Array,  # (B,) int32 live tokens per sequence
+    tile: int,  # tokens per block (divides T)
+) -> jax.Array:
+    """One-token attention through the page table, block by block.
+
+    Online-softmax merge in block order — the flash-decode structure the
+    Pallas kernel runs per grid step, on the same block-diagonal query
+    halves and ``tile``-token blocks.  A block past the live length (or of
+    an unclaimed ``-1`` page) leaves the state untouched, exactly like the
+    kernel's skipped steps.
+    """
+    B, KH, G, D = q.shape
+    S, T = k_pool.shape[:2]
+    F = KH * D
+    R = -(-(KH * G) // 16) * 16
+    q2 = expand_queries(q, R, k_pool.dtype)
+    kf = k_pool.reshape(S, T, F)
+    vf = v_pool.reshape(S, T, F)
+    m = jnp.full((B, R, 1), MASK_VALUE, jnp.float32)
+    l = jnp.zeros((B, R, 1), jnp.float32)
+    acc = jnp.zeros((B, R, F), jnp.float32)
     lengths = lengths.astype(jnp.int32)
-    for p in range(P):
-        slab = pages[:, p]  # (B,)
-        k = jnp.take(k_pool, jnp.maximum(slab, 0), axis=1)  # (KH, B, T, D)
-        v = jnp.take(v_pool, jnp.maximum(slab, 0), axis=1)
-        s = jnp.einsum("bkgd,kbtd->bkgt", q, k.astype(jnp.float32))
-        kpos = p * T + jnp.arange(T, dtype=jnp.int32)
-        live = kpos[None, :] < lengths[:, None]  # (B, T)
-        s = jnp.where(live[:, None, None, :], s, MASK_VALUE)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        pw = jnp.exp(s - m_new[..., None])
+    for j in range(pages.shape[1] * (T // tile)):
+        slab = pages[:, j * tile // T]  # (B,)
+        lo = j * tile % T
+        k = jnp.take(kf, jnp.maximum(slab, 0), axis=0)[:, lo : lo + tile]
+        v = jnp.take(vf, jnp.maximum(slab, 0), axis=0)[:, lo : lo + tile]
+        s2 = jnp.einsum("brf,btf->brt", q2, k, preferred_element_type=jnp.float32)
+        s = s2[:, :R] + s2[:, R:]
+        kpos = j * tile + jnp.arange(tile, dtype=jnp.int32)
+        live = kpos[None, :] < lengths[:, None]  # (B, tile)
+        s = jnp.where(live[:, None, :], s, MASK_VALUE)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        pw = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(pw, axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bkgt,kbtd->bkgd", pw, v.astype(jnp.float32)
+        l_new = l * alpha + jnp.sum(pw, axis=-1, keepdims=True)
+        pv2 = jnp.einsum(
+            "brt,btf->brf", split_halves(pw, v.dtype, 1), v,
+            preferred_element_type=jnp.float32,
         )
-        # page skipped entirely (kernel's pl.when) when dead for a sequence
-        use = ((slab >= 0) & (p * T < lengths))[:, None, None]
+        acc_new = acc * alpha + (pv2[:, :R] + pv2[:, R:])
+        # block skipped entirely (kernel's dead step) when dead for a row
+        use = ((slab >= 0) & (j * tile < lengths))[:, None, None]
         m = jnp.where(use, m_new, m)
         l = jnp.where(use, l_new, l)
-        acc = jnp.where(use[..., None], acc_new, acc)
-    return acc / jnp.maximum(l, 1e-30)[..., None]
+        acc = jnp.where(use, acc_new, acc)
+    return head_diagonal(acc / jnp.maximum(l, 1e-30), KH, G)
 
 
 def slab_append(

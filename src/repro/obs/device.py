@@ -66,10 +66,11 @@ SLOTS: tuple[str, ...] = (
     "paged_gather.launches",
     "paged_gather.tiles",       # page tiles with a live slab id (DMA'd work)
     "paged_gather.masked_tiles",  # −1 / padded page entries walked (waste)
-    # paged attend: flash-decode page walk (kernels/paged)
+    # paged attend: flash-decode page walk (kernels/paged); a tile is one
+    # (row, block) grid step over every KV head
     "paged_attend.launches",
-    "paged_attend.tiles",         # KV tiles entering the online softmax
-    "paged_attend.tiles_skipped",  # page steps gated off (tail slabs, −1)
+    "paged_attend.tiles",         # K/V blocks entering the online softmax
+    "paged_attend.tiles_skipped",  # steps gated off (tail blocks, −1, idle rows)
     "paged_attend.lanes",         # score lanes in visited tiles
     "paged_attend.masked_lanes",  # score lanes past kv_len in visited tiles
     # flatten: segmented gather (kernels/flatten)

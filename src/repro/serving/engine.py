@@ -669,7 +669,6 @@ class BatchEngine:
         cfg = self.cfg
         P = cfg.n_periods
         dt = jnp.dtype(cfg.dtype)
-        kh, dh = cfg.n_kv_heads, cfg.head_dim
         caches = []
         for kind in cfg.layout:
             if kind == "mamba":
@@ -684,14 +683,8 @@ class BatchEngine:
                 )
                 continue
             kv_dt = jnp.int8 if cfg.cache_quant else dt  # int8 codes + scales
-            c = {
-                "k_pool": jnp.zeros((P, 0, self.T, kh, dh), kv_dt),
-                "v_pool": jnp.zeros((P, 0, self.T, kh, dh), kv_dt),
-                "pages": jnp.full((P, self.B, self.book.max_pages), -1, jnp.int32),
-            }
-            if cfg.cache_quant:
-                c["ks_pool"] = jnp.zeros((P, 0, self.T, kh), jnp.bfloat16)
-                c["vs_pool"] = jnp.zeros((P, 0, self.T, kh), jnp.bfloat16)
+            c = kvcache.paged_pools(cfg, (P, 0), kv_dt)
+            c["pages"] = jnp.full((P, self.B, self.book.max_pages), -1, jnp.int32)
             if self._extent_mode:  # tuple-of-extents layout (one empty seed)
                 for key in ("k_pool", "v_pool", "ks_pool", "vs_pool"):
                     if key in c:
@@ -924,14 +917,16 @@ class BatchEngine:
             self._complete(req)
 
     def _set_slabs(self, pool, ids: np.ndarray, vals: jax.Array):
-        """``pool.at[:, ids].set(vals)`` across the flat or extent layout.
+        """``pool.at[:, ids].set(vals)`` across the flat or extent layout;
+        ``vals`` (P, n, T, …) takes the pool's item shape.
 
         ``ids`` are *host* slab ids, so extent routing is pure host
         arithmetic — one sliced scatter per extent that owns any of them.
         """
+        exts = list(kvcache._pool_exts(pool))
+        vals = vals.reshape(vals.shape[:3] + exts[0].shape[3:])
         if not self._extent_mode:
             return pool.at[:, jnp.asarray(ids, jnp.int32)].set(vals)
-        exts = list(pool)
         base = 0
         for e, size in enumerate(self._extent_sizes):
             sel = np.flatnonzero((ids >= base) & (ids < base + size))

@@ -130,16 +130,10 @@ def init_cache(
         maxp = max(-(-length_hint // T), 1)
         n_slabs = batch * maxp
         base = jnp.arange(n_slabs, dtype=jnp.int32).reshape(batch, maxp)
-        out = {
-            "k_pool": jnp.zeros((*lead, n_slabs, T, kh, dh), dtype),
-            "v_pool": jnp.zeros((*lead, n_slabs, T, kh, dh), dtype),
-            "pages": jnp.broadcast_to(base, (*lead, batch, maxp)).copy()
-            if lead
-            else base,
-        }
-        if quant:
-            out["ks_pool"] = jnp.zeros((*lead, n_slabs, T, kh), jnp.bfloat16)
-            out["vs_pool"] = jnp.zeros((*lead, n_slabs, T, kh), jnp.bfloat16)
+        out = paged_pools(cfg, (*lead, n_slabs), dtype)
+        out["pages"] = (
+            jnp.broadcast_to(base, (*lead, batch, maxp)).copy() if lead else base
+        )
         return out
     nlevels = needed_levels(cfg.cache_b0, length_hint)
     cache: Cache = {}
@@ -150,6 +144,22 @@ def init_cache(
             cache[f"ks{lvl}"] = zs(size)
             cache[f"vs{lvl}"] = zs(size)
     return cache
+
+
+def paged_pools(cfg: ModelConfig, slabs: tuple[int, ...], dtype) -> Cache:
+    """Zeroed K/V pools of ``slabs`` (leading dims + slab count) slabs of
+    ``cfg.slab_tokens`` tokens: K and V hold each token's heads side by
+    side, ``(…, T, KH·Dh)`` — the layout the decode kernel reads in place —
+    and int8 caches (``cfg.cache_quant``) add ``(…, T, KH)`` scale pools."""
+    T, kh = cfg.slab_tokens, cfg.n_kv_heads
+    out = {
+        "k_pool": jnp.zeros((*slabs, T, kh * cfg.head_dim), dtype),
+        "v_pool": jnp.zeros((*slabs, T, kh * cfg.head_dim), dtype),
+    }
+    if cfg.cache_quant:
+        out["ks_pool"] = jnp.zeros((*slabs, T, kh), jnp.bfloat16)
+        out["vs_pool"] = jnp.zeros((*slabs, T, kh), jnp.bfloat16)
+    return out
 
 
 def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -186,6 +196,10 @@ def _is_quant(cache: Cache) -> bool:
 
 
 # ---- segmented pools (pool/extents) ---------------------------------------
+# K/V pools hold each token's heads side by side (``paged_pools``): a TPU
+# tiles an array's two minor dims, so a trailing ``(KH, Dh)`` pair could
+# only be viewed as one row by copying the pool.  Writes take any item
+# shape of the right size; gathers hand back the pool's own.
 # A paged pool value (``k_pool``/``v_pool``/``ks_pool``/``vs_pool``) is
 # either a flat array (single-extent layout — the original trace) or a
 # tuple of extents: growth appended an extent instead of copying the pool,
@@ -208,6 +222,7 @@ def _scatter_pool(pool, slab: jax.Array, slot: jax.Array, vals: jax.Array):
     table; ``slab`` entries < 0 or ≥ n_slabs drop.  Returns the pool in its
     own structure (flat array or tuple of extents)."""
     exts = _pool_exts(pool)
+    vals = vals.reshape(vals.shape[0], *exts[0].shape[2:])
     if not isinstance(pool, (tuple, list)):
         S = exts[0].shape[0]
         tgt = jnp.where((slab >= 0) & (slab < S), slab, S)
@@ -258,6 +273,7 @@ def _scatter_slab(pool, slab: jax.Array, vals: jax.Array):
     """Whole-slab scatter (``pool.at[slab].set``) through the extent table;
     ``slab`` entries < 0 or ≥ n_slabs drop."""
     exts = _pool_exts(pool)
+    vals = vals.reshape(vals.shape[:2] + exts[0].shape[2:])
     if not isinstance(pool, (tuple, list)):
         S = exts[0].shape[0]
         tgt = jnp.where((slab >= 0) & (slab < S), slab, S)
@@ -283,7 +299,7 @@ def capacity_of(cache: Cache) -> int:
     allocator's, not the shape's.
     """
     if _is_paged(cache):
-        return cache["pages"].shape[-1] * _pool_first(cache["k_pool"]).shape[-3]
+        return cache["pages"].shape[-1] * _pool_first(cache["k_pool"]).shape[-2]
     if "k" in cache:
         return cache["k"].shape[-3]
     return indexing.capacity(cache["k0"].shape[-3], _levels(cache))
@@ -410,7 +426,7 @@ def append(
         # scatter through the page table: slab = pages[b, pos // T].  An
         # unclaimed page (−1) or out-of-table position drops the write —
         # the idle-slot / truncation semantics of the batch engine.
-        T = _pool_first(cache["k_pool"]).shape[-3]
+        T = _pool_first(cache["k_pool"]).shape[-2]
         maxp = cache["pages"].shape[-1]
         pidx = jnp.clip(pos // T, 0, maxp - 1)
         slab = cache["pages"][rows, pidx]
@@ -506,11 +522,13 @@ def attend(
     Returns (B, 1, H, Dh).  For ggarray caches this is the paper's bucket
     walk: one partial-softmax pass per level, merged online — the O(log n)
     'multiple pointers' cost the paper measures in Fig. 5 is the extra
-    per-level masking/merge here.  Paged caches walk the page table in the
-    same geometric segmentation (level b = pages [2^b−1, 2^(b+1)−1), padded
-    to the full level width), so with ``slab_tokens == cache_b0`` the result
-    is **bit-exact** vs the ggarray walk whenever ``length ≥ 1`` — stale
-    slab contents only ever sit behind exact-zero softmax weights.
+    per-level masking/merge here.  Paged caches take the flash-decode kernel
+    on a TPU (:func:`resolve_paged_attend`); elsewhere they walk the page
+    table in the same geometric segmentation (level b = pages [2^b−1,
+    2^(b+1)−1), padded to the full level width), so with ``slab_tokens ==
+    cache_b0`` the result is **bit-exact** vs the ggarray walk whenever
+    ``length ≥ 1`` — stale slab contents only ever sit behind exact-zero
+    softmax weights.
     """
     B, _, H, Dh = q.shape
     kh = cfg.n_kv_heads
@@ -531,7 +549,7 @@ def attend(
         return _dequant(ck, sk), _dequant(cv, sv)
 
     if _is_paged(cache):
-        out = _attend_paged(cache, qf, length, cfg, state, _kv)
+        out = _attend_paged(cache, qf, length, cfg, state)
         return out.reshape(B, 1, H, Dh).astype(q.dtype)
     if _is_ggarray(cache):
         n = _levels(cache)
@@ -576,6 +594,21 @@ def _gather_pool(pool, grp: jax.Array) -> jax.Array:
     return out.reshape(B, w * T, *exts[0].shape[2:])
 
 
+def _gather_kv(cache, grp: jax.Array, kh: int) -> tuple[jax.Array, jax.Array]:
+    """Gather a page group's K and V → each (B, w·T, KH, Dh), dequantised
+    to f32 for int8 pools."""
+    k = _gather_pool(cache["k_pool"], grp)
+    v = _gather_pool(cache["v_pool"], grp)
+    k = k.reshape(*k.shape[:-1], kh, -1)
+    v = v.reshape(*v.shape[:-1], kh, -1)
+    if not _is_quant(cache):
+        return k, v
+    return (
+        _dequant(k, _gather_pool(cache["ks_pool"], grp)),
+        _dequant(v, _gather_pool(cache["vs_pool"], grp)),
+    )
+
+
 def _levels_walk_ctr(pages, length, T: int, npools: int) -> jax.Array:
     """Device counters for the jnp geometric-levels walk: every level is
     gathered at its padded-to-power-of-two width (−1 pages included — the
@@ -609,25 +642,49 @@ def _levels_walk_ctr(pages, length, T: int, npools: int) -> jax.Array:
     })
 
 
-def _attend_paged(cache, qf, length, cfg, state, _kv):
-    """The paged walk: geometric page groups, or the flash-decode kernel."""
+PAGED_ATTEND_IMPLS = ("auto", "levels", "pallas")
+
+
+def resolve_paged_attend(impl: str, quant: bool) -> str:
+    """Resolve ``cfg.paged_attend_impl`` for one cache → ``"levels"`` or
+    ``"pallas"``.
+
+    ``"auto"`` takes the flash-decode kernel where Pallas compiles natively
+    (not ``common.should_interpret(None)``: a TPU) and the pool holds
+    unquantised K/V, and the ``levels`` walk otherwise: on the CPU, where
+    the walk is the bit-exact oracle against ``Engine``, and for int8
+    pools, which the kernel cannot dequantise.  Explicit ``"levels"`` and
+    ``"pallas"`` are honoured, except that an int8 pool always walks.
+    """
+    if impl not in PAGED_ATTEND_IMPLS:
+        raise ValueError(f"paged_attend_impl {impl!r} not in {PAGED_ATTEND_IMPLS}")
+    if quant:
+        return "levels"
+    if impl == "auto":
+        from repro.kernels import common
+
+        return "levels" if common.should_interpret(None) else "pallas"
+    return impl
+
+
+def _attend_paged(cache, qf, length, cfg, state):
+    """The paged walk: the flash-decode kernel, or geometric page groups."""
     from repro.pool.arena import geometric_page_groups
 
     pages = cache["pages"]
-    T = _pool_first(cache["k_pool"]).shape[-3]
-    if cfg.paged_attend_impl == "pallas" and not _is_quant(cache):
+    T = _pool_first(cache["k_pool"]).shape[-2]
+    if resolve_paged_attend(cfg.paged_attend_impl, _is_quant(cache)) == "pallas":
         from repro.kernels.paged import ops as paged_ops
 
         if cfg.instrument:
             out, vec = paged_ops.paged_attend(
                 qf, cache["k_pool"], cache["v_pool"], pages, length,
-                memory_space=cfg.kernel_memory_space, instrument=True,
+                instrument=True,
             )
             device.record(vec)
             return out
         return paged_ops.paged_attend(
-            qf, cache["k_pool"], cache["v_pool"], pages, length,
-            memory_space=cfg.kernel_memory_space,
+            qf, cache["k_pool"], cache["v_pool"], pages, length
         )
     if cfg.instrument:
         device.record(
@@ -641,12 +698,7 @@ def _attend_paged(cache, qf, length, cfg, state, _kv):
         grp = pages[:, lo:hi]
         if width < full:  # pad to the ggarray level width (exact no-op lanes)
             grp = jnp.pad(grp, ((0, 0), (0, full - width)), constant_values=-1)
-        kk, vv = _kv(
-            _gather_pool(cache["k_pool"], grp),
-            _gather_pool(cache["v_pool"], grp),
-            _gather_pool(cache["ks_pool"], grp) if "ks_pool" in cache else None,
-            _gather_pool(cache["vs_pool"], grp) if "vs_pool" in cache else None,
-        )
+        kk, vv = _gather_kv(cache, grp, cfg.n_kv_heads)
         kpos = lo * T + jnp.arange(full * T)
         state = _partial_scores(qf, kk, vv, kpos, length, state)
     m, l, acc = state
@@ -718,13 +770,8 @@ def chunk_attend(
     )
     quant = _is_quant(cache)
 
-    def _kv(ck, cv, sk, sv):
-        if not quant:
-            return ck, cv
-        return _dequant(ck, sk), _dequant(cv, sv)
-
     # ---- prefix: pool gather, fixed maxp·T width (one trace ∀ t0 > 0) ----
-    T = _pool_first(cache["k_pool"]).shape[-3]
+    T = _pool_first(cache["k_pool"]).shape[-2]
     Skv = pages_row.shape[0] * T
     if Skv and not first:
         if cfg.instrument:
@@ -737,12 +784,7 @@ def chunk_attend(
                 "paged_gather.masked_tiles": np_ * (pages_row.shape[0] - live_p),
             }))
         grp = pages_row[None]  # (1, maxp)
-        pk, pv_ = _kv(
-            _gather_pool(cache["k_pool"], grp),
-            _gather_pool(cache["v_pool"], grp),
-            _gather_pool(cache["ks_pool"], grp) if quant else None,
-            _gather_pool(cache["vs_pool"], grp) if quant else None,
-        )
+        pk, pv_ = _gather_kv(cache, grp, kh)
         cc = min(c, Skv)
         pad = (-Skv) % cc
         if pad:
@@ -795,7 +837,7 @@ def scatter_chunk(
     identical to a monolithic fill.  Dead lanes (pad, unclaimed page) route
     to the out-of-bounds slab and drop.
     """
-    T = _pool_first(cache["k_pool"]).shape[-3]
+    T = _pool_first(cache["k_pool"]).shape[-2]
     maxp = pages_row.shape[0]
     Cb = k_chunk.shape[1]
     quant = _is_quant(cache)
@@ -845,7 +887,7 @@ def fill_from_prefill(
     if _is_paged(cache):
         # page-sliced scatter: page p takes positions [p·T, (p+1)·T); rows
         # whose page is unclaimed drop (shorter sequences in the batch)
-        T = _pool_first(cache["k_pool"]).shape[-3]
+        T = _pool_first(cache["k_pool"]).shape[-2]
         maxp = cache["pages"].shape[-1]
         npages = min(-(-S // T), maxp)
         rows = jnp.arange(k_full.shape[0])

@@ -132,7 +132,10 @@ def test_paged_gather_parity(space, dtype, shape):
 
 @pytest.mark.parametrize("space", SPACES)
 @pytest.mark.parametrize("lengths", [[9, 2, 8, 1, 12], [1, 1, 1, 1, 1], [0, 5, 0, 3, 7]])
-def test_paged_attend_parity(space, lengths):
+def test_paged_attend_parity(space, lengths, monkeypatch):
+    """The attend has one tiling: whatever space the environment asks for,
+    it reads the pool through its scheduled blocks and matches the oracle."""
+    monkeypatch.setenv("REPRO_MEMORY_SPACE", space)
     rng = np.random.default_rng(zlib.crc32(repr((space, lengths)).encode()))
     S, T, N, P = 13, 4, 5, 3
     KH, G, D = 2, 3, 8
@@ -141,7 +144,7 @@ def test_paged_attend_parity(space, lengths):
     vp = jnp.asarray(rng.standard_normal((S, T, KH, D)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((N, KH, G, D)), jnp.float32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    got = paged_ops.paged_attend(q, kp, vp, pages, lengths, memory_space=space)
+    got = paged_ops.paged_attend(q, kp, vp, pages, lengths)
     want = paged_ops.paged_attend(q, kp, vp, pages, lengths, use_ref=True)
     _assert_trees_equal(got, want, f"attend {space}")
 

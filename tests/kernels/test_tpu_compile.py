@@ -5,13 +5,15 @@ Each kernel's ``hbm`` tiling — the TPU default — is lowered with
 that is described, not attached: the TPU compiler installed here refuses
 what the chip would (unaligned blocks, unsupported primitives, SMEM or VMEM
 overuse), and nothing runs.  Shapes are qwen2.5-3b's KV geometry (2 KV
-heads of 128, bf16) and the int32 payloads of the grow → freeze pipeline.
+heads of 128, bf16), the benchmark cells' decode geometries and the int32
+payloads of the grow → freeze pipeline.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and under several test workers only
 the worker given this file may load it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -49,8 +51,29 @@ def _compile(chip, fn, *shapes):
             return tuple(arg(e) for e in x)
         return jax.ShapeDtypeStruct(x[0], x[1], sharding=chip)
 
-    text = jax.jit(fn).lower(*map(arg, shapes)).compile().as_text()
-    assert "tpu_custom_call" in text
+    compiled = jax.jit(fn).lower(*map(arg, shapes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4}
+_FREE = ("custom-call", "parameter", "bitcast", "tuple", "get-tuple-element")
+
+
+def _instructions_at_least(text: str, limit: int) -> list[str]:
+    """Instructions of an optimized HLO module, other than the kernel's
+    custom call and free views, whose output holds ≥ ``limit`` bytes."""
+    big = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        if not m or m.group(1) not in _HLO_BYTES or m.group(3) in _FREE:
+            continue
+        n = _HLO_BYTES[m.group(1)]
+        for dim in filter(None, m.group(2).split(",")):
+            n *= int(dim)
+        if n >= limit:
+            big.append(line.strip()[:160])
+    return big
 
 
 KV = ((S, T, KH, D), jnp.bfloat16)
@@ -59,12 +82,41 @@ I32 = jnp.int32
 
 @pytest.mark.parametrize("extents", [1, 2])
 def test_paged_attend_compiles(chip, extents):
-    pools = KV if extents == 1 else (KV,) * extents
+    flat = ((S, T, KH * D), jnp.bfloat16)  # the engine's pool layout
+    pools = flat if extents == 1 else (flat,) * extents
     _compile(
         chip,
         lambda q, k, v, pg, ln: paged_ops.paged_attend(q, k, v, pg, ln, interpret=False),
         ((B, KH, G, D), jnp.float32), pools, pools, ((B, P), I32), ((B,), I32),
     )
+
+
+# The benchmark cells' decode geometries: pool slabs, slab tokens, KV heads,
+# head width, batch, pages per sequence, queries per KV head.
+CELLS = {
+    "chat": (64, 2048, 2, 128, 32, 5, 8),  # qwen2.5-3b
+    "longdoc": (48, 2048, 16, 64, 16, 5, 1),  # qwen1.5-0.5b
+}
+
+
+@pytest.mark.parametrize("extents", [1, 2])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_attend_reads_pool_in_place(chip, cell, extents):
+    """At the cells' real sizes the compiled attend is the kernel over the
+    pool as it lies: no instruction outside the custom call (a transpose, a
+    padded gather, a relayout or a prefetch copy) is as large as a pool
+    extent, and XLA keeps no pool-sized temporary."""
+    n_slabs, slab, kh, d, b, p, g = CELLS[cell]
+    ext = ((n_slabs // extents, slab, kh * d), jnp.bfloat16)
+    pools = ext if extents == 1 else (ext,) * extents
+    compiled = _compile(
+        chip,
+        lambda q, k, v, pg, ln: paged_ops.paged_attend(q, k, v, pg, ln, interpret=False),
+        ((b, kh, g, d), jnp.float32), pools, pools, ((b, p), I32), ((b,), I32),
+    )
+    ext_bytes = n_slabs // extents * slab * kh * d * 2
+    assert _instructions_at_least(compiled.as_text(), ext_bytes) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < ext_bytes
 
 
 @pytest.mark.parametrize("pool", [KV, ((1024, T), I32)], ids=["kv", "scalar"])

@@ -163,7 +163,8 @@ def test_paged_gather_counters_match_oracle(memory_space):
 
 
 @pytest.mark.parametrize("memory_space", SPACES)
-def test_paged_attend_counters_match_oracle(memory_space):
+def test_paged_attend_counters_match_oracle(memory_space, monkeypatch):
+    monkeypatch.setenv("REPRO_MEMORY_SPACE", memory_space)  # one tiling: no effect
     rng = np.random.default_rng(5)
     S, T, N, P, KH, G, D = 13, 4, 5, 3, 2, 3, 8
     pages = _fleet(rng, S, N, P, [3, 1, 2, 1, 3])
@@ -171,16 +172,15 @@ def test_paged_attend_counters_match_oracle(memory_space):
     vp = jnp.asarray(rng.standard_normal((S, T, KH, D)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((N, KH, G, D)), jnp.float32)
     lengths = jnp.asarray([9, 2, 8, 1, 12], jnp.int32)
-    out, vec = pg_ops.paged_attend(
-        q, kp, vp, pages, lengths, memory_space=memory_space, instrument=True
-    )
+    out, vec = pg_ops.paged_attend(q, kp, vp, pages, lengths, instrument=True)
     want_out, want_vec = pg_ops.paged_attend(
         q, kp, vp, pages, lengths, use_ref=True, instrument=True
     )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want_out))
     np.testing.assert_array_equal(np.asarray(vec), np.asarray(want_vec))
     d = device.as_dict(vec)
-    # visited tiles carry T score lanes each; waste = lanes past kv_len
+    # a visited tile is one (row, block) step over every KV head, T token
+    # lanes here (one block per slab); waste = lanes past kv_len
     assert d["paged_attend.lanes"] == d["paged_attend.tiles"] * T
     assert 0 < d["paged_attend.masked_lanes"] < d["paged_attend.lanes"]
     assert d["paged_attend.tiles_skipped"] > 0  # −1 pages were gated off

@@ -141,6 +141,31 @@ def test_batch_engine_pallas_attend_close_to_levels():
     assert same >= len(prompts) - 1
 
 
+@pytest.mark.parametrize(
+    "backend,impl,quant,want",
+    [
+        ("tpu", "auto", False, "pallas"),  # bf16 pool on the chip: the kernel
+        ("tpu", "auto", True, "levels"),  # int8 pool: the kernel cannot dequantise
+        ("cpu", "auto", False, "levels"),  # the CPU oracle walk
+        ("cpu", "auto", True, "levels"),
+        ("tpu", "levels", False, "levels"),  # explicit values are honoured
+        ("cpu", "pallas", False, "pallas"),
+        ("tpu", "pallas", True, "levels"),  # …but an int8 pool always walks
+    ],
+)
+def test_paged_attend_auto_resolution(monkeypatch, backend, impl, quant, want):
+    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert kvcache.resolve_paged_attend(impl, quant) == want
+
+
+def test_paged_attend_default_is_auto_and_checked():
+    cfg, _ = _setup()
+    assert cfg.paged_attend_impl == "auto"
+    with pytest.raises(ValueError):
+        kvcache.resolve_paged_attend("flash", False)
+
+
 def test_batch_engine_quant_cache_pools_are_int8():
     """cache_quant stores int8 codes + bf16 scales in the pools and still
     decodes the same tokens as the quantized ggarray Engine."""
