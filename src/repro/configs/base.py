@@ -30,6 +30,14 @@ class MoEConfig:
     # bucket levels instead of dropping at a fixed factor (DESIGN.md §3).
     ggarray_capacity: bool = False
     capacity_b0: int = 128
+    # Width of a shared SwiGLU expert that every token passes through beside
+    # its routed ones (0 = none).
+    d_ff_shared: int = 0
+    # Dropless held experts (models/moe ``_moe_held``): the router scores all
+    # ``n_experts``, this chip holds the share ``ModelConfig.held_experts``
+    # and computes every assignment that lands on it — no capacity, no drop,
+    # so a token's output never depends on what else is in the batch.
+    dropless: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +82,23 @@ class ModelConfig:
     # multimodal stub frontend: number of prefix embeddings provided by
     # input_specs() (ViT patches / audio frames), 0 = text-only
     n_prefix_embeds: int = 0
+    # every mixer (Mamba layers too) is followed by the norm → FFN sub-block;
+    # False keeps Mamba layers mixer-only (Jamba, Mamba-2)
+    mamba_ffn: bool = False
+    # position information: rotary embedding, or none (NoPE)
+    position_embedding: Literal["rope", "nope"] = "rope"
+    # muP multipliers (granite): token embeddings × embedding_multiplier,
+    # every residual branch × residual_multiplier, logits ÷ logits_scaling,
+    # attention scores × attention_multiplier (None: 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float | None = None
+    # expert parallelism: each MoE layer's experts are divided over
+    # ``expert_shards`` chips and this one holds share ``expert_shard``
+    # (dropless MoE only; the layer computes its own experts' part)
+    expert_shards: int = 1
+    expert_shard: int = 0
     # MLP activation
     activation: Literal["swiglu", "gelu", "relu"] = "swiglu"
     norm_eps: float = 1e-6
@@ -113,6 +138,13 @@ class ModelConfig:
             )
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"{self.name}: n_heads must divide by n_kv_heads")
+        if self.moe is not None and self.moe.n_experts % self.expert_shards:
+            raise ValueError(
+                f"{self.name}: {self.moe.n_experts} experts do not divide over "
+                f"{self.expert_shards} shards"
+            )
+        if not 0 <= self.expert_shard < self.expert_shards:
+            raise ValueError(f"{self.name}: expert_shard outside 0..{self.expert_shards - 1}")
 
     @property
     def head_dim(self) -> int:
@@ -142,6 +174,16 @@ class ModelConfig:
             return False
         return idx_in_period % self.moe.moe_period == self.moe.moe_offset
 
+    def has_ffn(self, kind: str) -> bool:
+        """Whether a layer of ``kind`` ends in the norm → FFN sub-block."""
+        return kind == "attn" or self.mamba_ffn
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the experts this chip holds in each MoE layer."""
+        n = self.moe.n_experts // self.expert_shards
+        return self.expert_shard * n, n
+
     # -- parameter counting (for roofline MODEL_FLOPS) --------------------
     def param_counts(self) -> dict[str, float]:
         """Total and active parameter counts (active ≙ per-token compute)."""
@@ -163,16 +205,20 @@ class ModelConfig:
         total = 0.0
         active = 0.0
         for i, kind in enumerate(self.layout):
-            if kind == "mamba":
-                total += mamba
-                active += mamba
+            mixer = mamba if kind == "mamba" else attn
+            total += mixer
+            active += mixer
+            if not self.has_ffn(kind):
                 continue
-            total += attn
-            active += attn
             if self.is_moe_layer(i):
-                e_mlp = 3 * d * self.moe.d_ff_expert
-                total += self.moe.n_experts * e_mlp + d * self.moe.n_experts
-                active += self.moe.top_k * e_mlp + d * self.moe.n_experts
+                moe = self.moe
+                e_mlp = 3 * d * moe.d_ff_expert
+                router = d * moe.n_experts
+                shared = 3 * d * moe.d_ff_shared
+                held = self.held_experts[1] if moe.dropless else moe.n_experts
+                total += held * e_mlp + router + shared
+                # per token: its top_k experts, of which held/n_experts land here
+                active += moe.top_k * held / moe.n_experts * e_mlp + router + shared
             else:
                 total += dense_mlp
                 active += dense_mlp

@@ -22,6 +22,7 @@ _MODULES = {
     "dbrx-132b": "repro.configs.dbrx_132b",
     "internvl2-26b": "repro.configs.internvl2_26b",
     "mamba2-2.7b": "repro.configs.mamba2_2_7b",
+    "granite-4.0-h-small": "repro.configs.granite_4_0_h_small",
 }
 
 ARCH_NAMES: tuple[str, ...] = tuple(_MODULES)
@@ -60,7 +61,7 @@ def reduced(name: str, **overrides) -> ModelConfig:
     if cfg.moe is not None:
         changes["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=128,
-            capacity_b0=4,
+            capacity_b0=4, d_ff_shared=128 if cfg.moe.d_ff_shared else 0,
         )
     if cfg.ssm is not None:
         changes["ssm"] = dataclasses.replace(

@@ -314,6 +314,13 @@ def project_qkv(p: Param, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if cfg.attention_multiplier is not None:
+        # every attend scales scores by 1/sqrt(head_dim); folding the rest
+        # of the multiplier into q gives scores × attention_multiplier
+        mult = cfg.attention_multiplier * cfg.head_dim ** 0.5
+        q = (q.astype(jnp.float32) * mult).astype(q.dtype)
+    if cfg.position_embedding == "nope":
+        return q, k, v
     cos, sin = rope(positions, cfg.head_dim, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)

@@ -20,6 +20,14 @@ Two execution paths:
     dry-run); the shard-local formulation keeps every buffer
     O(local_tokens).
 
+``_moe_held``     — the dropless serving layer (``MoEConfig.dropless``): the
+    router scores every expert in float32, this chip holds the share
+    ``cfg.held_experts`` of them and computes, for every token, the part of
+    the result its own experts give — each held expert runs on every token
+    of the batch, weighted by the token's gate (0 where not routed), so no
+    assignment is ever dropped and a token's output does not depend on the
+    rest of the batch.
+
 Expert capacity follows the GGArray geometry when ``ggarray_capacity`` is on:
 instead of a fixed capacity factor (drop on overflow — the static-array
 failure mode of §V), the buffer capacity snaps to the next geometric bucket
@@ -36,7 +44,9 @@ from repro.configs.base import ModelConfig, MoEConfig
 from repro.core import indexing
 from repro.core.insertion import insertion_offsets
 from repro.distributed.context import active_mesh, constrain
+from repro.models.mlp import init_mlp
 from repro.models.modules import Param, dense_init
+from repro.obs import device
 
 __all__ = ["init_moe", "moe_block", "expert_capacity"]
 
@@ -54,15 +64,21 @@ def expert_capacity(moe: MoEConfig, n_tokens: int) -> int:
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig, dtype) -> Param:
+    """Router over all ``n_experts``; expert weights for the experts held
+    here (all of them, unless the layer is dropless and sharded)."""
     moe = cfg.moe
     d, dff = cfg.d_model, moe.d_ff_expert
+    n = cfg.held_experts[1] if moe.dropless else moe.n_experts
     keys = jax.random.split(key, 4)
-    return {
+    p = {
         "router": dense_init(keys[0], (d, moe.n_experts), jnp.float32),
-        "w_gate": dense_init(keys[1], (moe.n_experts, d, dff), dtype, fan_in=d),
-        "w_up": dense_init(keys[2], (moe.n_experts, d, dff), dtype, fan_in=d),
-        "w_down": dense_init(keys[3], (moe.n_experts, dff, d), dtype, fan_in=dff),
+        "w_gate": dense_init(keys[1], (n, d, dff), dtype, fan_in=d),
+        "w_up": dense_init(keys[2], (n, d, dff), dtype, fan_in=d),
+        "w_down": dense_init(keys[3], (n, dff, d), dtype, fan_in=dff),
     }
+    if moe.d_ff_shared:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d, moe.d_ff_shared, "swiglu", dtype)
+    return p
 
 
 def _route_and_pack(p, xt, cfg, C):
@@ -164,11 +180,63 @@ def _moe_sharded(p: Param, x: jax.Array, cfg: ModelConfig, mesh):
     return out, aux
 
 
-def moe_block(p: Param, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
+def held_weights(p: Param, xt: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Each token's gate on each held expert, 0 where it was not routed there.
+
+    xt (T, D) → (T, n_held) float32.  The router scores all ``n_experts`` in
+    float32; a token's ``top_k`` logits are softmaxed among themselves (the
+    same weights as a full softmax renormalised over the chosen experts).
+    """
+    moe = cfg.moe
+    first, n = cfg.held_experts
+    logits = jnp.matmul(
+        xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )  # (T, E)
+    top, expert = jax.lax.top_k(logits, moe.top_k)  # (T, k)
+    gate = jax.nn.softmax(top, axis=-1)
+    hit = (expert - first)[..., None] == jnp.arange(n)  # (T, k, n)
+    return jnp.sum(jnp.where(hit, gate[..., None], 0.0), axis=1)
+
+
+def _moe_held(p: Param, x: jax.Array, cfg: ModelConfig, valid=None):
+    """Dropless held experts. x: (B, S, D) → (out, aux).
+
+    Every held expert runs on every token (capacity = tokens, which cannot
+    overflow); the gate weights the down-projection's input, so a token
+    that was not routed to an expert gets exactly nothing from it.
+    ``valid`` (B, S) marks real tokens for the device counters only.
+    """
+    B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    w = held_weights(p, xt, cfg)  # (T, n)
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", xt, p["w_gate"]))
+    h = h * jnp.einsum("td,edf->etf", xt, p["w_up"])
+    h = (h.astype(jnp.float32) * w.T[:, :, None]).astype(x.dtype)
+    out = jnp.einsum(
+        "etf,efd->td", h, p["w_down"], preferred_element_type=jnp.float32
+    ).astype(x.dtype)
+    if device.recording():
+        load = (w > 0).astype(jnp.float32)
+        if valid is not None:
+            load = load * valid.reshape(-1, 1).astype(jnp.float32)
+        load = jnp.sum(load, axis=0)  # (n,) assignments per held expert
+        device.record(device.pack(**{
+            "moe.held_assignments": jnp.sum(load),
+            "moe.max_held_load": jnp.max(load),
+        }))
+    return out.reshape(B, S, D), jnp.zeros((), jnp.float32)
+
+
+def moe_block(
+    p: Param, x: jax.Array, cfg: ModelConfig, valid: jax.Array | None = None
+) -> tuple[jax.Array, jax.Array]:
     """Top-k routed expert MLP. x: (B, S, D) → (out, aux_loss)."""
     mesh = active_mesh()
     moe = cfg.moe
     B, S, D = x.shape
+    if moe.dropless:
+        return _moe_held(p, x, cfg, valid)
     if mesh is not None and "model" in mesh.shape:
         tp = mesh.shape["model"]
         dp = 1

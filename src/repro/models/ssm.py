@@ -91,8 +91,16 @@ def mamba_block(
     state: MambaState | None = None,
     *,
     return_state: bool = False,
+    lengths: jax.Array | None = None,
 ):
     """Full-sequence SSD pass. x: (B, L, D) → (B, L, D) [, final MambaState].
+
+    ``lengths`` (B,) masks the recurrence by length: rows at and past a
+    sequence's length are padding (a bucket-padded prefill tail, or the
+    right-padding of a ragged batch).  Their ``dt`` is zeroed, which makes
+    them state-neutral (decay exp(0) = 1, input dt·B·x = 0), and the conv
+    window handed on is the one ending at the live end, so the final state
+    is exactly that of the unpadded sequence.
 
     ``state`` makes this a *resumable* chunk step (serving's chunked
     prefill): ``state.ssd`` seeds the inter-chunk recurrence and
@@ -120,7 +128,11 @@ def mamba_block(
     xBC = jnp.concatenate([hist, xBC], axis=1)  # (B, d_conv-1 + L, ch)
     # raw (pre-conv) tail → the next step's conv window; the history concat
     # keeps it full-width even for L < d_conv-1 prompts
-    conv_tail = xBC[:, xBC.shape[1] - (s.d_conv - 1) :, :]
+    if lengths is None:
+        conv_tail = xBC[:, xBC.shape[1] - (s.d_conv - 1) :, :]
+    else:  # the d_conv-1 rows that end at each sequence's live end
+        rows = jnp.asarray(lengths, jnp.int32)[:, None] + jnp.arange(s.d_conv - 1)
+        conv_tail = jnp.take_along_axis(xBC, rows[:, :, None], axis=1)
     if pad:  # pad to a chunk multiple; dt is zeroed on pad steps below, which
         # makes them state-neutral (decay=exp(0)=1, contribution dt·B·x=0)
         z = jnp.pad(z, ((0, 0), (0, pad), (0, 0)))
@@ -133,7 +145,10 @@ def mamba_block(
     Cm = Cm.reshape(B, Lp, g, n)
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B, Lp, nh)
-    if pad:
+    if lengths is not None:
+        live = jnp.arange(Lp)[None, :] < jnp.asarray(lengths, jnp.int32)[:, None]
+        dt = dt * live.astype(dt.dtype)[:, :, None]
+    elif pad:
         dt = dt * (jnp.arange(Lp) < L).astype(dt.dtype)[None, :, None]
     A = -jnp.exp(p["A_log"])  # (nh,)
     dA = dt * A  # (B, Lp, nh) log-decay

@@ -22,23 +22,91 @@ from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.modules import Param, dense_init, embed, rms_norm, unembed
 
-__all__ = ["init_params", "forward", "init_period_layers"]
+__all__ = [
+    "init_params", "forward", "init_period_layers", "embed_tokens", "add_branch",
+    "ffn", "head_logits",
+]
+
+
+# --------------------------------------------------------------------------
+# The layer's pieces, shared by ``forward`` and the serving steps
+# (serving/steps.py): one definition of the embedding, the residual branch,
+# the FFN sub-block after a mixer, and the output head.
+# --------------------------------------------------------------------------
+
+def embed_tokens(params: Param, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Token embeddings in the compute dtype, × ``embedding_multiplier``."""
+    x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def add_branch(x: jax.Array, y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The residual stream plus one sub-block's output (× the multiplier)."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def ffn(
+    sp: Param, x: jax.Array, slot: int, cfg: ModelConfig, valid: jax.Array | None = None
+) -> tuple[jax.Array, jax.Array]:
+    """The norm → FFN sub-block that follows a mixer → (x, aux loss).
+
+    MoE slots run ``moe_block`` (router and routed experts) under the named
+    scope ``moe`` and a shared expert, where the layer has one, under
+    ``mlp``; other slots a dense MLP under ``mlp``.  The residual add joins
+    the last of them.  ``valid`` marks real tokens for the MoE's device
+    counters.
+    """
+    with jax.named_scope("norm"):
+        h = constrain(rms_norm(x, sp["norm2"], cfg.norm_eps), ("batch", "seq", None))
+    aux = jnp.zeros((), jnp.float32)
+    if not cfg.is_moe_layer(slot):
+        with jax.named_scope("mlp"):
+            return add_branch(x, mlp_mod.mlp_block(sp["mlp"], h, cfg.activation), cfg), aux
+    with jax.named_scope("moe"):
+        out, aux = moe_mod.moe_block(sp["moe"], h, cfg, valid)
+        if not cfg.moe.d_ff_shared:
+            return add_branch(x, out, cfg), aux
+    with jax.named_scope("mlp"):  # the shared expert, once for every token
+        out = out + mlp_mod.mlp_block(sp["moe"]["shared"], h, "swiglu")
+        return add_branch(x, out, cfg), aux
+
+
+def head_logits(params: Param, x: jax.Array, cfg: ModelConfig, axes: tuple) -> jax.Array:
+    """Final norm → output head → logits (÷ ``logits_scaling``), with the
+    vocabulary's padding columns masked.  ``axes`` names x's leading dims
+    and the vocabulary's for the sharding constraint."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+    logits = constrain(unembed(x, table), axes)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    if cfg.padded_vocab != cfg.vocab_size:  # mask vocab-padding columns
+        live = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+        logits = jnp.where(live, logits, -1e30)
+    return logits
 
 
 def _init_slot(key: jax.Array, slot: int, kind: str, cfg: ModelConfig, dtype) -> Param:
     d = cfg.d_model
     p: Param = {"norm1": jnp.ones((d,), dtype)}
-    if kind == "mamba":
-        p["mamba"] = ssm_mod.init_mamba(key, cfg, dtype)
-        return p
     k1, k2, k3 = jax.random.split(key, 3)
-    p["attn"] = attn_mod.init_attention(k1, cfg, dtype)
+    if kind == "mamba":
+        # the slot key itself: mixer-only Mamba stacks draw the same weights
+        p["mamba"] = ssm_mod.init_mamba(key, cfg, dtype)
+    else:
+        p["attn"] = attn_mod.init_attention(k1, cfg, dtype)
+    if not cfg.has_ffn(kind):
+        return p
     p["norm2"] = jnp.ones((d,), dtype)
     if cfg.is_moe_layer(slot):
         p["moe"] = moe_mod.init_moe(k2, cfg, dtype)
     else:
         p["mlp"] = mlp_mod.init_mlp(k2, d, cfg.d_ff, cfg.activation, dtype)
-    if cfg.n_enc_layers:  # enc-dec decoder: cross-attention sub-block
+    if cfg.n_enc_layers and kind == "attn":  # enc-dec decoder: cross-attention
         p["cross_norm"] = jnp.ones((d,), dtype)
         p["cross"] = attn_mod.init_attention(k3, cfg, dtype)
     return p
@@ -97,18 +165,14 @@ def _apply_slot(
     # upcast — halves every activation collective's payload (§Perf).
     h = constrain(rms_norm(x, sp["norm1"], cfg.norm_eps), ("batch", "seq", None))
     if kind == "mamba":
-        x = x + ssm_mod.mamba_block(sp["mamba"], h, cfg)
-        return x, aux
-    x = x + attn_mod.attention_block(sp["attn"], h, cfg, positions)
+        x = add_branch(x, ssm_mod.mamba_block(sp["mamba"], h, cfg), cfg)
+    else:
+        x = add_branch(x, attn_mod.attention_block(sp["attn"], h, cfg, positions), cfg)
     if memory_kv is not None:
         h = constrain(rms_norm(x, sp["cross_norm"], cfg.norm_eps), ("batch", "seq", None))
         x = x + attn_mod.attention_block(sp["cross"], h, cfg, positions, kv=memory_kv)
-    h = constrain(rms_norm(x, sp["norm2"], cfg.norm_eps), ("batch", "seq", None))
-    if cfg.is_moe_layer(slot):
-        out, aux = moe_mod.moe_block(sp["moe"], h, cfg)
-        x = x + out
-    else:
-        x = x + mlp_mod.mlp_block(sp["mlp"], h, cfg.activation)
+    if cfg.has_ffn(kind):
+        x, aux = ffn(sp, x, slot, cfg)
     return x, aux
 
 
@@ -126,7 +190,7 @@ def forward(
     token embeddings (VLM patches / audio frames).  ``memory``: (B, Senc, D)
     encoder output for enc-dec cross-attention.
     """
-    x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    x = embed_tokens(params, tokens, cfg)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     x = constrain(x, ("batch", "seq", None))
@@ -162,10 +226,4 @@ def forward(
 
     body = jax.checkpoint(period_body) if cfg.remat else period_body
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = constrain(unembed(x, table), ("batch", None, "vocab"))
-    if cfg.padded_vocab != cfg.vocab_size:  # mask vocab-padding columns
-        live = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-        logits = jnp.where(live, logits, -1e30)
-    return logits, aux
+    return head_logits(params, x, cfg, ("batch", None, "vocab")), aux

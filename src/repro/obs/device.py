@@ -81,6 +81,9 @@ SLOTS: tuple[str, ...] = (
     "slab_append.waves",
     "slab_append.lanes",
     "slab_append.active_lanes",
+    # held experts: dropless MoE (models/moe._moe_held), per layer call
+    "moe.held_assignments",  # routed (token, expert) pairs that land here
+    "moe.max_held_load",     # Σ over calls of the busiest held expert's load
 )
 NSLOTS = len(SLOTS)
 SLOT_INDEX: dict[str, int] = {name: i for i, name in enumerate(SLOTS)}

@@ -499,7 +499,8 @@ class BatchEngine:
             hybrid = "mamba" in cfg.layout
             # Bit-exactness alignment (DESIGN.md §7): chunk boundaries must
             # land on the monolithic attention grid, and on the SSD chunk
-            # grid for hybrid layouts.
+            # grid for hybrid layouts (whose padded tails are masked out of
+            # the recurrence by length).
             if "attn" in cfg.layout and C % cfg.attention_chunk:
                 raise ValueError(
                     f"prefill_chunk={C} must be a multiple of "
@@ -512,7 +513,7 @@ class BatchEngine:
                 )
             self.sched = sched_mod.Scheduler(
                 self.book, slab_tokens=self.T, chunk=C,
-                exact_tail=hybrid, max_chunks_per_step=max_chunks_per_step,
+                max_chunks_per_step=max_chunks_per_step,
                 obs=self.obs,
             )
         # prefix cache (DESIGN.md §10): completed prompts publish their full

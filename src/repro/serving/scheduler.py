@@ -19,10 +19,10 @@ so the scheduling invariants are property-testable in isolation
 Bucketed padding is what bounds compilation: every chunk is one of
 ``bucket_widths(b0, chunk)`` widths (``b0·2^i`` up to ``chunk``), so a fleet
 of arbitrary prompt lengths compiles **O(log chunk)** prefill traces instead
-of one per distinct length.  ``exact_tail=True`` (hybrid SSM layouts) opts
-the *final* chunk out of padding: pad tokens are exactly dead lanes for
-attention (DESIGN.md §7 bit-exactness contract) but would pollute the Mamba
-conv/SSD recurrence (``dt = softplus(dt_bias) ≠ 0`` on pad rows).
+of one per distinct length.  Pad tokens are exactly dead lanes for
+attention (DESIGN.md §7 bit-exactness contract), and hybrid layouts mask
+them out of the Mamba conv/SSD recurrence by the chunk's live length
+(``models/ssm.mamba_block``), so every layout pads its final chunk too.
 
 Admission order is FIFO with bounded skip-ahead: a request whose slab need
 cannot be covered is skipped (smaller later requests may still admit — the
@@ -98,7 +98,6 @@ class Scheduler:
         slab_tokens: int,
         chunk: int,
         buckets: tuple[int, ...] | None = None,
-        exact_tail: bool = False,
         max_chunks_per_step: int | None = None,
         starvation_limit: int = 4,
         obs: ServingTimeline | None = None,
@@ -114,7 +113,6 @@ class Scheduler:
         )
         if self.buckets[-1] != chunk:
             raise ValueError(f"buckets {self.buckets} must end at chunk={chunk}")
-        self.exact_tail = exact_tail
         self.starvation_limit = starvation_limit
         B = len(book.npages)
         self.B = B
@@ -237,10 +235,7 @@ class Scheduler:
             L = int(self.length[slot])
             live = min(self.C, L - t0)
             final = t0 + live >= L
-            if final and self.exact_tail:
-                width = live
-            else:
-                width = bucket_for(live, self.buckets)
+            width = bucket_for(live, self.buckets)
             cover = self.slabs_for(t0 + live)
             new = max(cover - int(self.book.npages[slot]), 0)
             out.append(

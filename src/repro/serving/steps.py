@@ -24,9 +24,8 @@ from repro.configs.base import ModelConfig
 from repro.distributed.context import constrain
 from repro.models import ssm as ssm_mod
 from repro.models.attention import inner_attention, project_out, project_qkv
-from repro.models.mlp import mlp_block
-from repro.models.moe import moe_block
-from repro.models.modules import embed, rms_norm, unembed
+from repro.models.modules import rms_norm
+from repro.models.transformer import add_branch, embed_tokens, ffn, head_logits
 
 from repro.obs import device
 from repro.serving import kvcache
@@ -41,30 +40,17 @@ __all__ = [
 ]
 
 
+# ``ssm``: a Mamba layer's mixer (projections, conv, SSD scan or recurrence,
+# gated norm) with its conv/SSD state rows read and written; ``moe``: the
+# router, the held experts and their combine (a shared expert is ``mlp``).
 SCOPES = (
     "embed", "norm", "qkv", "pool_read", "kv_append", "attend", "out_proj",
-    "mlp", "pool_write", "lm_head",
+    "mlp", "pool_write", "lm_head", "ssm", "moe",
 )
 
 
 def logits_from_hidden(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = constrain(unembed(x, table), ("batch", "vocab"))
-    if cfg.padded_vocab != cfg.vocab_size:
-        live = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-        logits = jnp.where(live, logits, -1e30)
-    return logits
-
-
-def _mlp_or_moe(sp, x, slot, cfg):
-    with jax.named_scope("norm"):
-        h = rms_norm(x, sp["norm2"], cfg.norm_eps)
-    with jax.named_scope("mlp"):
-        if cfg.is_moe_layer(slot):
-            out, _ = moe_block(sp["moe"], h, cfg)
-            return x + out
-        return x + mlp_block(sp["mlp"], h, cfg.activation)
+    return head_logits(params, x, cfg, ("batch", "vocab"))
 
 
 # Cache slots ride the period scan with a leading (n_periods, …) stacking;
@@ -108,7 +94,7 @@ def prefill(
 ) -> tuple[jax.Array, list]:
     """→ (last-position logits (B, V), caches list[slot])."""
     policy = cfg.cache_policy if policy is None else policy
-    x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    x = embed_tokens(params, tokens, cfg)
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     B, S, _ = x.shape
@@ -123,24 +109,28 @@ def prefill(
             sp = period_params[slot]
             h = rms_norm(x, sp["norm1"], cfg.norm_eps)
             if kind == "mamba":
-                y, state = ssm_mod.mamba_block(sp["mamba"], h, cfg, return_state=True)
-                x = x + y
-                caches_out.append({"conv": state.conv, "ssd": state.ssd})
-                continue
-            q, k, v = project_qkv(sp["attn"], h, cfg, positions)
-            att = inner_attention(q, k, v, cfg, causal=True)
-            x = x + project_out(sp["attn"], att)
-            cache = kvcache.init_cache(cfg, B, cap, policy)
-            cache = kvcache.fill_from_prefill(cache, k, v)
-            if memory is not None:
-                ck = jnp.einsum("bsd,dhk->bshk", memory, sp["cross"]["wk"])
-                cv = jnp.einsum("bsd,dhk->bshk", memory, sp["cross"]["wv"])
-                hc = rms_norm(x, sp["cross_norm"], cfg.norm_eps)
-                qc = jnp.einsum("bsd,dhk->bshk", hc, sp["cross"]["wq"])
-                attc = inner_attention(qc, ck, cv, cfg, causal=False)
-                x = x + project_out(sp["cross"], attc)
-                cache = dict(cache, cross_k=ck, cross_v=cv)
-            x = _mlp_or_moe(sp, x, slot, cfg)
+                # right-padded rows of shorter prompts stay out of the state
+                y, state = ssm_mod.mamba_block(
+                    sp["mamba"], h, cfg, return_state=True, lengths=lengths
+                )
+                x = add_branch(x, y, cfg)
+                cache = {"conv": state.conv, "ssd": state.ssd}
+            else:
+                q, k, v = project_qkv(sp["attn"], h, cfg, positions)
+                att = inner_attention(q, k, v, cfg, causal=True)
+                x = add_branch(x, project_out(sp["attn"], att), cfg)
+                cache = kvcache.init_cache(cfg, B, cap, policy)
+                cache = kvcache.fill_from_prefill(cache, k, v)
+                if memory is not None:
+                    ck = jnp.einsum("bsd,dhk->bshk", memory, sp["cross"]["wk"])
+                    cv = jnp.einsum("bsd,dhk->bshk", memory, sp["cross"]["wv"])
+                    hc = rms_norm(x, sp["cross_norm"], cfg.norm_eps)
+                    qc = jnp.einsum("bsd,dhk->bshk", hc, sp["cross"]["wq"])
+                    attc = inner_attention(qc, ck, cv, cfg, causal=False)
+                    x = x + project_out(sp["cross"], attc)
+                    cache = dict(cache, cross_k=ck, cross_v=cv)
+            if cfg.has_ffn(kind):
+                x, _ = ffn(sp, x, slot, cfg)
             caches_out.append(cache)
         return (x,), caches_out
 
@@ -178,7 +168,9 @@ def prefill_chunk(
     chunk (prefilling slots are inert under concurrent decode steps), so the
     claimed pages arrive as the separate ``pages_row`` operand.  K/V scatter
     targets the claimed slabs; Mamba layers run the resumable SSD block
-    against the slot's state row.  Logits only matter on the final chunk.
+    against the slot's state row, masked by ``live`` so the padding of a
+    bucket-padded tail never enters the recurrence.  Logits only matter on
+    the final chunk.
 
     ``first`` must be static (it is known at chunk-planning time): the first
     chunk runs from a ZERO recurrence — a reused slot's state rows still
@@ -187,9 +179,10 @@ def prefill_chunk(
     walk outright (every prefix lane is dead at t0 = 0).
     """
     with jax.named_scope("embed"):
-        x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+        x = embed_tokens(params, tokens, cfg)
     Cb = tokens.shape[1]
     positions = (t0 + jnp.arange(Cb))[None, :]  # (1, Cb) global positions
+    valid = (jnp.arange(Cb) < live)[None, :]  # (1, Cb) live lanes of the chunk
 
     def period_body(carry, xs):
         if cfg.instrument:
@@ -204,33 +197,41 @@ def prefill_chunk(
         with scope as t:
             for lslot, kind in enumerate(cfg.layout):
                 sp = period_params[lslot]
+                if kind == "mamba":
+                    with jax.named_scope("norm"):
+                        h = rms_norm(x, sp["norm1"], cfg.norm_eps)
+                    with jax.named_scope("ssm"):
+                        c = _cache_get(caches[lslot], idx)
+                        st = (
+                            None
+                            if first
+                            else ssm_mod.MambaState(
+                                conv=c["conv"][slot][None], ssd=c["ssd"][slot][None]
+                            )
+                        )
+                        # the padded tail is masked out of the recurrence
+                        y, st = ssm_mod.mamba_block(
+                            sp["mamba"], h, cfg, state=st, return_state=True,
+                            lengths=live[None],
+                        )
+                        caches[lslot] = _cache_put(
+                            caches[lslot],
+                            {
+                                "conv": c["conv"].at[slot].set(
+                                    st.conv[0].astype(c["conv"].dtype)
+                                ),
+                                "ssd": c["ssd"].at[slot].set(st.ssd[0]),
+                            },
+                            idx,
+                        )
+                        x = add_branch(x, y, cfg)
+                    if cfg.has_ffn(kind):
+                        x, _ = ffn(sp, x, lslot, cfg, valid)
+                    continue
                 with jax.named_scope("pool_read"):
                     c = _cache_get(caches[lslot], idx)
                 with jax.named_scope("norm"):
                     h = rms_norm(x, sp["norm1"], cfg.norm_eps)
-                if kind == "mamba":
-                    st = (
-                        None
-                        if first
-                        else ssm_mod.MambaState(
-                            conv=c["conv"][slot][None], ssd=c["ssd"][slot][None]
-                        )
-                    )
-                    y, st = ssm_mod.mamba_block(
-                        sp["mamba"], h, cfg, state=st, return_state=True
-                    )
-                    x = x + y
-                    caches[lslot] = _cache_put(
-                        caches[lslot],
-                        {
-                            "conv": c["conv"].at[slot].set(
-                                st.conv[0].astype(c["conv"].dtype)
-                            ),
-                            "ssd": c["ssd"].at[slot].set(st.ssd[0]),
-                        },
-                        idx,
-                    )
-                    continue
                 with jax.named_scope("qkv"):
                     q, k, v = project_qkv(sp["attn"], h, cfg, positions)
                 with jax.named_scope("attend"):
@@ -238,10 +239,10 @@ def prefill_chunk(
                         c, pages_row, q, k, v, t0, live, cfg, first=first
                     )
                 with jax.named_scope("out_proj"):
-                    x = x + project_out(sp["attn"], att)
+                    x = add_branch(x, project_out(sp["attn"], att), cfg)
                 with jax.named_scope("kv_append"):
                     c2 = kvcache.scatter_chunk(c, pages_row, k, v, t0, live, cfg)
-                x = _mlp_or_moe(sp, x, lslot, cfg)
+                x, _ = ffn(sp, x, lslot, cfg, valid)
                 with jax.named_scope("pool_write"):
                     caches[lslot] = _cache_put(caches[lslot], c2, idx)
         if cfg.instrument:
@@ -326,10 +327,11 @@ def decode_step(
     """
     token = token.reshape(token.shape[0], 1)
     with jax.named_scope("embed"):
-        x = embed(params["embed"], token).astype(jnp.dtype(cfg.dtype))
+        x = embed_tokens(params, token, cfg)
     B = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
     positions = pos[:, None]  # (B, 1)
+    valid = (pos > 0)[:, None]  # rows with a sequence (idle/prefilling: 0)
 
     def period_body(carry, xs):
         # caches ride the CARRY and are updated in place (dynamic-update-
@@ -347,24 +349,30 @@ def decode_step(
         with scope as t:
             for slot, kind in enumerate(cfg.layout):
                 sp = period_params[slot]
+                if kind == "mamba":
+                    with jax.named_scope("norm"):
+                        h = rms_norm(x, sp["norm1"], cfg.norm_eps)
+                    with jax.named_scope("ssm"):
+                        c = _cache_get(caches[slot], idx)
+                        y, st = ssm_mod.mamba_decode_step(
+                            sp["mamba"], h, ssm_mod.MambaState(c["conv"], c["ssd"]), cfg
+                        )
+                        new_conv, new_ssd = st.conv, st.ssd
+                        if active is not None:
+                            keep = active[:, None, None]
+                            new_conv = jnp.where(keep, new_conv, c["conv"])
+                            new_ssd = jnp.where(keep[..., None], new_ssd, c["ssd"])
+                        caches[slot] = _cache_put(
+                            caches[slot], {"conv": new_conv, "ssd": new_ssd}, idx
+                        )
+                        x = add_branch(x, y, cfg)
+                    if cfg.has_ffn(kind):
+                        x, _ = ffn(sp, x, slot, cfg, valid)
+                    continue
                 with jax.named_scope("pool_read"):
                     c = _cache_get(caches[slot], idx)
                 with jax.named_scope("norm"):
                     h = rms_norm(x, sp["norm1"], cfg.norm_eps)
-                if kind == "mamba":
-                    y, st = ssm_mod.mamba_decode_step(
-                        sp["mamba"], h, ssm_mod.MambaState(c["conv"], c["ssd"]), cfg
-                    )
-                    x = x + y
-                    new_conv, new_ssd = st.conv, st.ssd
-                    if active is not None:
-                        keep = active[:, None, None]
-                        new_conv = jnp.where(keep, new_conv, c["conv"])
-                        new_ssd = jnp.where(keep[..., None], new_ssd, c["ssd"])
-                    caches[slot] = _cache_put(
-                        caches[slot], {"conv": new_conv, "ssd": new_ssd}, idx
-                    )
-                    continue
                 with jax.named_scope("qkv"):
                     q, k, v = project_qkv(sp["attn"], h, cfg, positions)
                 kv_only = {key: val for key, val in c.items() if not key.startswith("cross")}
@@ -373,7 +381,7 @@ def decode_step(
                 with jax.named_scope("attend"):
                     att = kvcache.attend(c2, q, pos + 1, cfg)
                 with jax.named_scope("out_proj"):
-                    x = x + project_out(sp["attn"], att)
+                    x = add_branch(x, project_out(sp["attn"], att), cfg)
                 if "cross_k" in c:
                     hc = rms_norm(x, sp["cross_norm"], cfg.norm_eps)
                     qc = jnp.einsum("bsd,dhk->bshk", hc, sp["cross"]["wq"])
@@ -383,7 +391,7 @@ def decode_step(
                         jnp.full((B,), enc_len, jnp.int32), cfg,
                     )
                     x = x + project_out(sp["cross"], attc)
-                x = _mlp_or_moe(sp, x, slot, cfg)
+                x, _ = ffn(sp, x, slot, cfg, valid)
                 with jax.named_scope("pool_write"):
                     caches[slot] = _cache_put(caches[slot], c2, idx)
         if cfg.instrument:

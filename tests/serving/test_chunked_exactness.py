@@ -54,11 +54,9 @@ def test_chunked_matches_monolithic_and_engine_multichunk():
 
 
 def test_chunked_matches_engine_hybrid_equal_length():
-    """Hybrid (Mamba+attn) stack vs the batched Engine oracle.
-
-    Equal-length prompts only: the oracle right-pads ragged batches
-    through the Mamba recurrence, so raggedness is covered by the
-    chunked-vs-monolithic test below instead.
+    """Hybrid (Mamba+attn) stack vs the batched Engine oracle, equal-length
+    prompts (raggedness is covered by the chunked-vs-monolithic test below,
+    and the oracle's own ragged batches by tests/bench/test_bench_granite.py).
     """
     cfg, params = _setup("jamba-v0.1-52b")
     prompts = _prompts([40, 40, 40], seed=3)
@@ -68,7 +66,7 @@ def test_chunked_matches_engine_hybrid_equal_length():
     )
     be = BatchEngine(params, cfg, max_batch=3, admission="chunked")
     assert be.run_all(prompts, t_new) == want
-    assert be.stats.prefill_chunks == 6  # 40 = 32 + 8-token exact tail
+    assert be.stats.prefill_chunks == 6  # 40 = 32 + an 8-token tail
     be.check_free_list()
 
 

@@ -15,7 +15,8 @@ every event that
 * admission is FIFO within equal slab need, with bounded skip-ahead
   (no request starves: the aged head blocks the queue until it fits);
 * chunk plans tile ``[0, L)`` exactly, widths drawn from the bucket set
-  (or the exact tail when ``exact_tail=True``).
+  (the final chunk too: hybrid layouts mask its padding out of the
+  recurrence instead of compiling its exact width).
 """
 import collections
 
@@ -92,13 +93,17 @@ def test_chunks_tile_prompt_exactly():
     assert sched.decoding == [0] and not sched.prefilling
 
 
-def test_exact_tail_skips_padding():
-    book, sched = _mk(chunk=8, slab_tokens=4, exact_tail=True)
-    sched.submit(0, length=21)
+@pytest.mark.parametrize("length", [1, 5, 8, 13, 21, 23])
+def test_final_chunk_pads_to_its_bucket(length):
+    """Every tail takes the smallest bucket that holds it, so the widths a
+    fleet compiles are the bucket set, whatever its prompt lengths."""
+    book, sched = _mk(chunk=8, slab_tokens=4)
+    sched.submit(0, length=length)
     sched.admit(_grow(book))
     tasks = _run_prefill(book, sched)
-    assert [t.width for t in tasks] == [8, 8, 5]  # tail unpadded
-    assert tasks[-1].final
+    tail = tasks[-1]
+    assert tail.final and tail.width == bucket_for(tail.live, sched.buckets)
+    assert all(t.width in sched.buckets for t in tasks)
 
 
 def test_match_hook_shrinks_reservation_to_uncached_suffix():
